@@ -82,16 +82,6 @@ def test_sidecar_builds_mesh_and_ring_from_config():
     a serving sidecar whose engine runs the mesh/ring path — and its
     Assign must agree with a single-device engine on the same
     snapshot."""
-    import pytest as _pytest
-
-    from tpusched.ring import SHARD_MAP_2D_MESH_OK
-
-    if not SHARD_MAP_2D_MESH_OK:
-        _pytest.skip(
-            "0.4.x experimental shard_map mis-routes the ppermute ring "
-            "on 2D meshes (see tpusched/ring.py); the (4, 2) mesh this "
-            "test configures hits exactly that"
-        )
     from tpusched import Engine
     from tpusched.rpc.client import SchedulerClient, assign_response_arrays
     from tpusched.rpc.codec import snapshot_to_proto

@@ -169,3 +169,22 @@ def test_ia_ok_at_choice_matches_full_matrix():
             kpair.ia_ok_at_choice(snap, st2, static.sig_match, choice, esn)
         )
         np.testing.assert_array_equal(got, want, err_msg=f"seed {seed}")
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_fast_capacity_exact_past_f32_prefix(seed):
+    """Node capacity one millicore short of k+1 identical pods, with
+    enough pods that a global request prefix passes 2^24: the commit's
+    per-node sums must stay exact, never admitting pod k+1 (the first
+    10k x 5k chip run over-filled a node by one millicore this way)."""
+    rng = np.random.default_rng(seed)
+    cfg = fast_cfg()
+    b = SnapshotBuilder(cfg)
+    for i in range(40):
+        b.add_node(f"n{i}", {"cpu": float(rng.integers(20, 40) * 9999 - 1)})
+    for j in range(2000):
+        b.add_pod(f"p{j}", {"cpu": 9999.0},
+                  priority=float(rng.integers(0, 100)))
+    snap, _ = b.build()
+    res = Engine(cfg).solve(snap)
+    check_valid(snap, res, cfg)

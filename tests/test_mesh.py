@@ -23,7 +23,7 @@ from tpusched import Engine, EngineConfig
 from tpusched.engine import _sat_tables
 from tpusched.kernels.assign import score_batch, solve_rounds, solve_sequential
 from tpusched.mesh import make_mesh, matrix_sharding, shard_snapshot, snapshot_shardings
-from tpusched.synth import make_cluster
+from tpusched.synth import config2_scale, make_cluster
 
 MESH_SHAPES = [
     (8, 1),
@@ -95,6 +95,22 @@ def test_sharded_fast_matches_single(rng, shape):
     mesh = make_mesh(shape, devices=jax.devices()[: shape[0] * shape[1]])
     sharded = jax.jit(mk(mesh))(shard_snapshot(mesh, snap))
     np.testing.assert_array_equal(np.asarray(single[0]), np.asarray(sharded[0]))
+
+
+@pytest.mark.parametrize("shape", [(4, 1), (2, 2)])
+def test_sharded_fast_nosig_state_bitwise(shape):
+    """No-signature fast rounds (BASELINE config 2): placements AND node
+    usage equal the single-device solve bit for bit — every pod-axis f32
+    reduction is exact, so sharding cannot reorder its rounding (a
+    duplicate scatter-add of `used` once could)."""
+    snap, _ = config2_scale(np.random.default_rng(0), 300, 80,
+                            with_qos=True)
+    cfg = EngineConfig(mode="fast")
+    mesh = make_mesh(shape)
+    single = Engine(cfg).solve(snap)
+    sharded = Engine(cfg, mesh=mesh).solve(snap)
+    np.testing.assert_array_equal(single.assignment, sharded.assignment)
+    np.testing.assert_array_equal(single.final_used, sharded.final_used)
 
 
 @pytest.mark.parametrize("shape", [

@@ -344,3 +344,19 @@ def test_locale_independent_float_parse():
         )
     finally:
         locale.setlocale(locale.LC_NUMERIC, "C")
+
+
+def test_build_is_keyed_by_source_hash(tmp_path, monkeypatch):
+    """The loaded .so is named for the SHA-256 of the committed source,
+    so a build of any other source (a stale file copied along with the
+    tree, whatever its mtime) is never picked up."""
+    import os
+
+    src = tmp_path / "fastdecode.cc"
+    with open(native._SRC, "rb") as f:
+        src.write_bytes(f.read())
+    monkeypatch.setattr(native, "_SRC", str(src))
+    before = native._so_path()
+    assert os.path.basename(before).startswith("_fastdecode-")
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    assert native._so_path() != before

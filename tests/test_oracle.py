@@ -4,10 +4,11 @@ the spec — these tests pin its semantics before parity tests compare the
 TPU path against it."""
 
 import numpy as np
+import pytest
 
 from tpusched import EngineConfig, SnapshotBuilder
 from tpusched.config import PluginWeights, QoSConfig
-from tpusched.oracle import Oracle
+from tpusched.oracle import EFFECT_NO_EXECUTE, EFFECT_NO_SCHEDULE, Oracle
 from tpusched.snapshot import (
     MatchExpression,
     NodeSelectorTerm,
@@ -206,3 +207,29 @@ def test_interpod_sees_previously_assigned_pending_pods():
     # follower must be in the same zone as wherever leader went
     zones = snap.nodes.domain[:, 0]
     assert zones[res.assignment[1]] == zones[lead_node]
+
+
+def _taints_ok_loop(ora, p):
+    """The per-node loop Oracle.taints_ok replaced (the reference)."""
+    tids = np.asarray(ora.nodes.taint_ids)
+    effect = np.asarray(ora.snap.taint_effect)
+    tol = np.asarray(ora.pods.tolerated)[p]
+    ok = np.ones(tids.shape[0], bool)
+    for n in range(tids.shape[0]):
+        for t in tids[n]:
+            if t >= 0 and effect[t] in (EFFECT_NO_SCHEDULE,
+                                        EFFECT_NO_EXECUTE) and not tol[t]:
+                ok[n] = False
+    return ok
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_taints_ok_matches_loop_reference(seed):
+    from tpusched.synth import make_cluster
+
+    snap, _ = make_cluster(np.random.default_rng(seed), 40, 30,
+                           taint_frac=0.5, toleration_frac=0.4)
+    ora = Oracle(snap, EngineConfig())
+    for p in range(snap.pods.valid.shape[0]):
+        np.testing.assert_array_equal(ora.taints_ok(p),
+                                      _taints_ok_loop(ora, p))

@@ -11,6 +11,7 @@ the serve-cause compile count never moves; the chaos harness
 """
 
 import logging
+import pathlib
 
 import pytest
 
@@ -337,22 +338,31 @@ def test_close_cancels_inflight_prewarm():
 # Persistent compilation cache wiring
 
 
-def test_enable_persistent_cache_sets_jax_config(tmp_path, monkeypatch):
+@pytest.mark.parametrize("from_env", [False, True],
+                         ids=["checkout-default", "env-placed"])
+def test_enable_persistent_cache_sets_jax_config(tmp_path, monkeypatch,
+                                                 from_env):
+    """One helper places the cache: $JAX_COMPILATION_CACHE_DIR when set
+    (JAX reads it itself, so the code sets no directory), else
+    <checkout>/.xla_cache."""
     import jax
 
     prev = jax.config.jax_compilation_cache_dir
+    sentinel = str(tmp_path / "untouched")
     try:
-        target = tmp_path / "xla-cache"
-        got = shapeclass.enable_persistent_cache(str(target))
-        assert got == str(target)
-        assert target.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(target)
-        # Env fallback: no explicit path -> $TPUSCHED_COMPILE_CACHE.
-        env_dir = tmp_path / "from-env"
-        monkeypatch.setenv(shapeclass.CACHE_ENV, str(env_dir))
-        assert shapeclass.enable_persistent_cache() == str(env_dir)
-        assert env_dir.is_dir()
-        monkeypatch.delenv(shapeclass.CACHE_ENV)
-        assert shapeclass.enable_persistent_cache() is None
+        jax.config.update("jax_compilation_cache_dir", sentinel)
+        if from_env:
+            env_dir = str(tmp_path / "from-env")
+            monkeypatch.setenv(shapeclass.CACHE_ENV, env_dir)
+            assert shapeclass.enable_persistent_cache() == env_dir
+            assert jax.config.jax_compilation_cache_dir == sentinel
+        else:
+            monkeypatch.delenv(shapeclass.CACHE_ENV, raising=False)
+            want = str(pathlib.Path(__file__).resolve().parents[1]
+                       / ".xla_cache")
+            assert shapeclass.enable_persistent_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert pathlib.Path(want).is_dir()
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)

@@ -54,11 +54,6 @@ def test_ring_counts_match_dense(ndev, assign_some):
     np.testing.assert_array_equal(ring, dense)
 
 
-@pytest.mark.skipif(
-    not __import__("tpusched.ring", fromlist=["x"]).SHARD_MAP_2D_MESH_OK,
-    reason="0.4.x experimental shard_map mis-routes the ppermute ring on "
-           "2D meshes (see tpusched/ring.py); 1D 'p' rings are exact",
-)
 def test_ring_counts_multins():
     """Namespace-scoped signatures survive the ring path (on a 2D
     mesh — the namespace semantics themselves are 1D-mesh-covered by

@@ -110,7 +110,7 @@ def main():
         def body(i, used):
             u2, choice, val = _deal_commit(
                 snap.nodes.allocatable, snap.pods.requests, used,
-                feasible, masked, allowed, rank, 8,
+                feasible, masked, allowed, rank, 8, cum_width=P,
             )
             return used + 1e-12 * val[0]
         return body

@@ -698,6 +698,29 @@ def _spread_waterfill_deal(snap: ClusterSnapshot, pair_st, used, relaxed,
     return cand, val, ok
 
 
+def _segment_cumsum(x, boundary, width: "int | None" = None):
+    """Inclusive prefix sum of x [L, R] along axis 0, restarting at
+    every row where boundary [L] is True. Every partial sum stays inside
+    one segment (one node's demand), so integer-valued requests stay
+    exact in f32; a global cumsum minus a segment offset does not — at
+    10k pods its cpu prefix passes 2^24 and the differences round,
+    which let the fast commit over-fill a node by a millicore. Rows are
+    zero-padded to `width` first: the scan's tree depends only on its
+    length, so a view padded to the full width sums exactly like the
+    full-width call."""
+    L = x.shape[0]
+    if width is not None and width > L:
+        x = jnp.concatenate([x, jnp.zeros((width - L, x.shape[1]), x.dtype)])
+        boundary = jnp.concatenate([boundary, jnp.zeros(width - L, bool)])
+
+    def comb(a, b):
+        (av, af), (bv, bf) = a, b
+        return jnp.where(bf[..., None], bv, av + bv), af | bf
+
+    out, _ = jax.lax.associative_scan(comb, (x, boundary))  # tpl: disable=TPL202(segmented prefix-SUM: the tree depends only on the length, which every caller fixes — full [P], or padded to `width` — so the result is width-invariant; partial sums never cross a segment)
+    return out[:L]
+
+
 def _node_add(used, node, mask, requests, rank, width: int, sign=1.0):
     """used.at[node[p]].add(sign * requests[p]) for masked rows, as ONE
     unique-index add per node: rows sort by (node, rank), per-node
@@ -717,22 +740,10 @@ def _node_add(used, node, mask, requests, rank, width: int, sign=1.0):
     node_s = node_m[perm]
     mask_s = mask[perm]
     req_s = jnp.where(mask_s[:, None], requests[perm], 0.0)
-    if width > P:
-        req_pad = jnp.concatenate(
-            [req_s, jnp.zeros((width - P, req_s.shape[1]), req_s.dtype)]
-        )
-    else:
-        req_pad = req_s
-    cum = jnp.cumsum(req_pad, axis=0)[:P]                    # [P, R]  # tpl: disable=TPL202(this IS the width-pad idiom: width > P concatenates zeros out to `width`; width == P is already the full layout — both branches cumsum exactly `width` rows, which the branch-join analysis cannot see)
-    idx = jnp.arange(P, dtype=jnp.int32)
     boundary = jnp.concatenate(
         [jnp.ones(1, bool), node_s[1:] != node_s[:-1]]
     )
-    seg_start = jax.lax.cummax(jnp.where(boundary, idx, 0))
-    offset = jnp.where(
-        (seg_start > 0)[:, None], cum[jnp.clip(seg_start - 1, 0, None)], 0.0
-    )
-    total = cum - offset                                     # incl. own row
+    total = _segment_cumsum(req_s, boundary, width)          # incl. own row
     is_last = jnp.concatenate([node_s[1:] != node_s[:-1], jnp.ones(1, bool)])
     is_last &= mask_s
     # Non-last rows add exact 0.0 at node 0 (a no-op); last rows hit
@@ -746,28 +757,24 @@ def _node_add(used, node, mask, requests, rank, width: int, sign=1.0):
 def _deal_commit(allocatable, requests, used, feasible, masked, allowed,
                  rank, K: int, dealt_override=None,
                  dealt_override_val=None, dealt_override_ok=None,
-                 score_full=None, tie_pick=None,
-                 rank_is_sorted: bool = False,
-                 cum_width: "int | None" = None):
+                 score_full=None, tie_pick=None, *, cum_width: int):
     """One round's dealing + capacity-prefix conflict resolution +
     rescue, shape-generic over the pod axis (used on the full [P, N]
-    matrices and on the compacted residual view — same math per pod;
-    see _RESIDUAL_CAP for the f32 reduction-order caveat). Returns
+    matrices and on compacted views — same math per pod). Returns
     (used2, choice, chosen_val); choice[p] = committed node or -1.
 
-    cum_width (the frontier-compaction contract, ISSUE 12): when set,
-    every f32 reduction over the pod axis is made WIDTH-INVARIANT so a
-    compacted [F, N] call is bitwise-identical to the full-width [P, N]
-    call it stands in for: node desirability sums go through int32
+    cum_width (>= the full pod width; rank values index it): every f32
+    reduction over the pod axis is made WIDTH- and SHARDING-INVARIANT,
+    so a compacted [F, N] call is bitwise-identical to the full-width
+    [P, N] call it stands in for, and a pod-sharded call to a
+    single-device one: node desirability sums go through int32
     fixed-point (integer adds are associativity-exact; f32 column sums
-    change with the reduction tree when the row count changes), demand
-    and per-node capacity prefixes cumsum over arrays padded/scattered
-    to `cum_width` rows (identical layouts at any view width — real
-    rows front-packed or rank-scattered, zeros elsewhere), and `used`
-    updates apply as unique-per-node segment totals (_node_add) instead
-    of order-unspecified duplicate scatter-adds. None keeps the legacy
-    reductions (the no-signature paths, whose residual compaction
-    predates — and documents — the non-bitwise caveat).
+    change with the reduction tree when the row count or the sharding
+    changes), demand and per-node capacity prefixes run over arrays
+    padded/scattered to `cum_width` rows (identical layouts at any view
+    width — real rows front-packed or rank-scattered, zeros elsewhere),
+    and `used` updates apply as unique-per-node segment totals
+    (_node_add) instead of order-unspecified duplicate scatter-adds.
 
     Load-balancing scores give every pod nearly the SAME global node
     ranking, so per-pod argmax/top-K concentrates all commits on the
@@ -791,24 +798,19 @@ def _deal_commit(allocatable, requests, used, feasible, masked, allowed,
     BIG = jnp.int32(2**31 - 1)
     allowed_col = allowed[:, None]
     n_allowed = jnp.maximum(allowed.sum(), 1)
-    if cum_width is None:
-        desir = jnp.sum(  # tpl: disable=TPL201(legacy cum_width=None reduction kept as the documented _RESIDUAL_CAP non-bitwise caveat — the nosig residual compaction predates the width-invariance contract; the sig path always passes cum_width and takes the int32 fixed-point branch below)
-            jnp.where(feasible & allowed_col, masked, 0.0), axis=0
-        ) / n_allowed                                        # [N]
-    else:
-        # Fixed-point desirability (see docstring): 1/16 granularity is
-        # ample for a dealing-order heuristic, and clipping bounds the
-        # int32 column sums at P * (2^15 - 1) (exact for P <= 64k; the
-        # old +-2^15 bound could reach exactly 2^31 and wrap — TPL204.
-        # The clip never binds in practice: scores are O(400), so
-        # |round(contrib*16)| tops out around 6400).
-        contrib = jnp.where(feasible & allowed_col, masked, 0.0)
-        iq = jnp.clip(
-            jnp.round(contrib * 16.0), -32767.0, 32767.0
-        ).astype(jnp.int32)
-        desir = jnp.sum(iq, axis=0).astype(jnp.float32) / (
-            16.0 * n_allowed.astype(jnp.float32)
-        )
+    # Fixed-point desirability (see docstring): 1/16 granularity is
+    # ample for a dealing-order heuristic, and clipping bounds the
+    # int32 column sums at P * (2^15 - 1) (exact for P <= 64k; the
+    # old +-2^15 bound could reach exactly 2^31 and wrap — TPL204.
+    # The clip never binds in practice: scores are O(400), so
+    # |round(contrib*16)| tops out around 6400).
+    contrib = jnp.where(feasible & allowed_col, masked, 0.0)
+    iq = jnp.clip(
+        jnp.round(contrib * 16.0), -32767.0, 32767.0
+    ).astype(jnp.int32)
+    desir = jnp.sum(iq, axis=0).astype(jnp.float32) / (
+        16.0 * n_allowed.astype(jnp.float32)
+    )
     desir = jnp.where(
         jnp.any(feasible & allowed_col, axis=0), desir, NEG_INF
     )
@@ -818,24 +820,14 @@ def _deal_commit(allocatable, requests, used, feasible, masked, allowed,
         jnp.isfinite(desir)[:, None], remaining, 0.0
     )
     # Inclusive cumulative demand of allowed pods in rank order,
-    # WITHOUT sorting (a [P] argsort costs ~4.5 ms on this TPU's sort
-    # path, per round, and the two rank layouts both admit a sortless
-    # form): rank_is_sorted views (tranches, drains — sel was chosen
-    # by rank) cumsum directly; full-width callers have rank as a
-    # permutation of 0..P-1 and scatter into rank-major layout.
+    # WITHOUT sorting (a [P] argsort per round costs more than the
+    # scatter): the view's demand lands at GLOBAL rank positions of a
+    # [cum_width, R] array — byte-identical to the full-width
+    # rank-major scatter (absent pods demand 0) — so the f32 prefix
+    # sums agree bitwise at any view width.
     dem = jnp.where(allowed[:, None], requests, 0.0)
-    if cum_width is not None:
-        # Width-invariant layout: scatter the view's demand at GLOBAL
-        # rank positions of a [cum_width, R] array — byte-identical to
-        # the full-width rank-major scatter (absent pods demand 0) — so
-        # the f32 prefix sums agree bitwise at any view width.
-        rm = jnp.zeros((cum_width, dem.shape[1]), dem.dtype).at[rank].set(dem)
-        my_dem = jnp.cumsum(rm, axis=0)[rank]                # [P, R]
-    elif rank_is_sorted:
-        my_dem = jnp.cumsum(dem, axis=0)                     # [P, R]  # tpl: disable=TPL201(legacy rank_is_sorted demand prefix at the view's own fixed width — the documented nosig non-bitwise caveat; sig-path callers pass cum_width and take the width-padded branch)
-    else:
-        rm = jnp.zeros_like(dem).at[rank].set(dem)
-        my_dem = jnp.cumsum(rm, axis=0)[rank]                # [P, R]
+    rm = jnp.zeros((cum_width, dem.shape[1]), dem.dtype).at[rank].set(dem)
+    my_dem = jnp.cumsum(rm, axis=0)[rank]                    # [P, R]
     cum_rem = jnp.cumsum(remaining[node_order], axis=0)      # [N, R]  # tpl: disable=TPL201(node-axis capacity prefix at fixed [N] — the node axis is never view-compacted; dealing estimate only, corrected by the capacity-prefix commit and re-tried next round on a miss)
     pos = jnp.zeros(P, jnp.int32)
     for ri in range(cum_rem.shape[1]):
@@ -906,27 +898,15 @@ def _deal_commit(allocatable, requests, used, feasible, masked, allowed,
         cand_s = cand_m[perm]
         act_s = active[perm]
         req_s = jnp.where(act_s[:, None], requests[perm], 0.0)
-        if cum_width is not None and cum_width > P:
-            # Active rows front-pack identically at any width (inactive
-            # rows sort to the sentinel tail with zero demand), so a
-            # zero-padded cumsum is bitwise width-invariant.
-            req_pad = jnp.concatenate([
-                req_s, jnp.zeros((cum_width - P, req_s.shape[1]),
-                                 req_s.dtype),
-            ])
-            cum = jnp.cumsum(req_pad, axis=0)[:P]            # [P, R]
-        else:
-            cum = jnp.cumsum(req_s, axis=0)                  # [P, R]  # tpl: disable=TPL201(else-branch of the width-pad idiom: cum_width None or == P means this width IS the full layout; the compacted sig path always takes the padded branch above)
         idx = jnp.arange(P, dtype=jnp.int32)
         boundary = jnp.concatenate(
             [jnp.ones(1, bool), cand_s[1:] != cand_s[:-1]]
         )
         seg_start = jax.lax.cummax(jnp.where(boundary, idx, 0))
-        offset = jnp.where(
-            (seg_start > 0)[:, None],
-            cum[jnp.clip(seg_start - 1, 0, None)], 0.0,
-        )
-        within = cum - offset                                # incl. own
+        # Active rows front-pack identically at any width (inactive
+        # rows sort to the sentinel tail with zero demand), so the
+        # zero-padded scan is bitwise width-invariant.
+        within = _segment_cumsum(req_s, boundary, cum_width)  # incl. own
         cap_node = jnp.clip(cand_s, 0, N - 1)
         fits = jnp.all(
             used_j[cap_node] + within <= allocatable[cap_node],
@@ -938,13 +918,8 @@ def _deal_commit(allocatable, requests, used, feasible, masked, allowed,
         commit_s = fits & prefix_ok
         commit_j = jnp.zeros(P, bool).at[perm].set(commit_s)
         nofit = jnp.zeros(P, bool).at[perm].set(bad)
-        if cum_width is not None:
-            used_j = _node_add(used_j, cand, commit_j, requests, rank,
-                               cum_width)
-        else:
-            used_j = used_j.at[jnp.clip(cand, 0, N - 1)].add(  # tpl: disable=TPL203(legacy cum_width=None commit add — the documented nosig non-bitwise caveat; the sig path routes through _node_add's unique-per-node totals in the branch above)
-                jnp.where(commit_j[:, None], requests, 0.0)
-            )
+        used_j = _node_add(used_j, cand, commit_j, requests, rank,
+                           cum_width)
         choice_j = jnp.where(commit_j, cand, choice_j)
         # Only pods whose own node is full advance their pointer;
         # prefix-blocked pods retry the same node next sub-step.
@@ -1035,11 +1010,10 @@ def _fallback_depth(N: int) -> int:
 # 10k x 5k; headline fast p50 295 -> 185 ms). Semantically equivalent:
 # with no pairwise signatures a round's outcome depends only on
 # (pending set, node used), both preserved by the view including
-# relative rank order. NOT bitwise: the shared node-desirability mean
-# in _deal_commit reduces over a different-shaped array, so f32
-# rounding can flip near-tied node rankings (34/10000 placements moved
-# at the headline shape, all audit-valid — validate_assignment: 0
-# violations).
+# relative rank order. NOT bitwise with full-width rounds: the shared
+# node-desirability mean in _deal_commit is taken over the view's
+# pods, not every pending pod, so near-tied node rankings can flip
+# (all audit-valid).
 _RESIDUAL_CAP = 1024
 
 # Bid width and round cap of the fast-mode batched preemption auction
@@ -1316,7 +1290,7 @@ def _preempt_rounds(cfg: EngineConfig, snap: ClusterSnapshot,
                 alloc, pods.requests[dsel], used, feas_d, masked_d,
                 jnp.any(feas_d, axis=1), rank[dsel], _fallback_depth(N),
                 tie_pick=pick_node_batch(cfg, masked_d, dsel),
-                rank_is_sorted=True,
+                cum_width=P,
             )
             hit_d = choice_d >= 0
             assigned = assigned.at[dsel].set(
@@ -1438,7 +1412,7 @@ def _preempt_rounds(cfg: EngineConfig, snap: ClusterSnapshot,
             nodes.allocatable, req_sel, used, feas_c, masked_c,
             allowed_c, rank[sel], _fallback_depth(N),
             tie_pick=pick_node_batch(cfg, masked_c, sel),
-            rank_is_sorted=True,
+            cum_width=P,
         )
         keep_pl = choice_pl >= 0
         keep_all = keep | keep_pl
@@ -1660,12 +1634,13 @@ def _cycle_nosig(alloc, used, req, mask, sscore, w_lr, w_ba, w_ts, rw):
 
 
 def _make_round_nosig(cfg, alloc, req, mask, sscore, valid, rank, pod_ids,
-                      w_lr, w_ba, w_ts, rw, max_rounds, K,
-                      round_cap=None, rank_is_sorted=False):
+                      w_lr, w_ba, w_ts, rw, max_rounds, K, cum_width,
+                      round_cap=None):
     """(cond, body) for the no-signature commit rounds over whatever
     pod-axis width the given arrays carry. pod_ids: original pod
     indices of the rows (seeded tie-break hashes by pod identity, so
-    compacted views pick like full-width ones). round_cap: optional
+    compacted views pick like full-width ones). cum_width: the full
+    pod width (rank values index it; _deal_commit). round_cap: optional
     (start_r, n) — stop after n rounds from start_r even with commits
     left (tranche mode: stragglers carry into the next tranche instead
     of dribbling through 1-commit fixpoint rounds). State: (used,
@@ -1689,7 +1664,7 @@ def _make_round_nosig(cfg, alloc, req, mask, sscore, valid, rank, pod_ids,
         used2, choice, chosen_val = _deal_commit(
             alloc, req, used, feasible, masked, allowed, rank, K,
             tie_pick=pick_node_batch(cfg, masked, pod_ids),
-            rank_is_sorted=rank_is_sorted,
+            cum_width=cum_width,
         )
         commit = choice >= 0
         asg2 = jnp.where(commit, choice, asg)
@@ -1730,7 +1705,7 @@ def _solve_rounds_nosig(cfg: EngineConfig, snap: ClusterSnapshot,
         cfg, nodes.allocatable, pods.requests, static.mask, static.score,
         pods.valid, rank, jnp.arange(P, dtype=jnp.int32),
         static.w_lr, static.w_ba, static.w_ts,
-        static.rw, max_rounds, K,
+        static.rw, max_rounds, K, P,
     )
     if init is None:
         init = (
@@ -1804,8 +1779,7 @@ def _solve_rounds_nosig(cfg: EngineConfig, snap: ClusterSnapshot,
                 cfg, alloc, req[sel], static.mask[sel],
                 static.score[sel], real, rank[sel], sel,
                 static.w_lr[sel], static.w_ba[sel], static.w_ts[sel],
-                static.rw, 2**30, K, round_cap=(r, tranche_cap),
-                rank_is_sorted=True,
+                static.rw, 2**30, K, P, round_cap=(r, tranche_cap),
             )
             init_c = (
                 used, jnp.full(C, -1, jnp.int32),
@@ -2239,16 +2213,12 @@ def _capacity_prefix_keep(alloc, used_base, requests, node, rank, active):
     node_s = node_m[perm]
     act_s = active[perm]
     req_s = jnp.where(act_s[:, None], requests[perm], 0.0)
-    cum = jnp.cumsum(req_s, axis=0)  # tpl: disable=TPL201(carried-placement capacity prefix at the lineage's fixed full [P] width — never view-compacted; mirrors _deal_commit's commit rule, and a spill only re-enters the frontier (re-solved), never overflows)
     idx = jnp.arange(P, dtype=jnp.int32)
     boundary = jnp.concatenate(
         [jnp.ones(1, bool), node_s[1:] != node_s[:-1]]
     )
     seg_start = jax.lax.cummax(jnp.where(boundary, idx, 0))
-    offset = jnp.where(
-        (seg_start > 0)[:, None], cum[jnp.clip(seg_start - 1, 0, None)], 0.0
-    )
-    within = cum - offset
+    within = _segment_cumsum(req_s, boundary)
     cap_node = jnp.clip(node_s, 0, N - 1)
     fits = jnp.all(
         used_base[cap_node] + within <= alloc[cap_node], axis=-1

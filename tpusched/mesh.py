@@ -51,12 +51,14 @@ def init_distributed(
 
 
 def make_mesh(shape: tuple[int, int] | None = None, devices=None) -> Mesh:
-    """Mesh of shape (p, n). Default: all devices on the 'p' axis (pod
+    """Mesh of shape (p, n) over the first p*n of `devices` (default
+    jax.devices()). Default shape: all devices on the 'p' axis (pod
     sharding scales first; node-axis sharding pays collective cost on
     every per-pod reduction)."""
-    devices = devices if devices is not None else jax.devices()
+    devices = list(devices if devices is not None else jax.devices())
     if shape is None:
         shape = (len(devices), 1)
+    devices = devices[: shape[0] * shape[1]]
     arr = np.asarray(devices).reshape(shape)
     return Mesh(arr, (POD_AXIS, NODE_AXIS))
 

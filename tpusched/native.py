@@ -7,7 +7,10 @@ large snapshots, which matters because decode — not the TPU solve — is
 the sidecar's serving bottleneck at 10k x 5k (SURVEY.md §7 hard part 6).
 
 Build-on-demand: the .so is compiled with g++ on first use and cached
-next to this file (atomic rename; lock-guarded). No pybind11 — plain
+next to this file under a name keyed by the SHA-256 of
+native/fastdecode.cc (atomic rename; lock-guarded), so a build from any
+other source — a stale file copied along with the tree, say — is never
+loaded. No pybind11 — plain
 CPython C API + numpy headers. Everything degrades gracefully to the
 Python decoder when a compiler is unavailable, and codec.decode_snapshot
 falls back to the Python path on any native decode error.
@@ -21,6 +24,7 @@ ASCII literals, underscores, inf/nan (any case) all match exactly.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import importlib.util
 import os
 import subprocess
@@ -42,19 +46,26 @@ from tpusched.snapshot import (
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native",
                     "fastdecode.cc")
-_SO = os.path.join(os.path.dirname(__file__), "_fastdecode.so")
 
 _mod = None
 _build_failed: str | None = None
 _load_lock = __import__("threading").Lock()
 
 
-def _build_so() -> None:
+def _so_path() -> str:
+    """Where the build of the committed source lives."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(__file__),
+                        f"_fastdecode-{digest}.so")
+
+
+def _build_so(so: str) -> None:
     # Compile to a private temp path and os.replace into place: g++
     # writes -o non-atomically, and concurrent first-callers (the
     # sidecar's thread pool, or a server and a bench sharing the
     # checkout) must never dlopen a half-written file.
-    tmp = f"{_SO}.build-{os.getpid()}"
+    tmp = f"{so}.build-{os.getpid()}"
     cmd = [
         "g++", "-O2", "-shared", "-fPIC", "-std=c++17",
         f"-I{sysconfig.get_paths()['include']}",
@@ -68,7 +79,7 @@ def _build_so() -> None:
         except OSError:
             pass
         raise RuntimeError(f"native build failed:\n{proc.stderr[-2000:]}")
-    os.replace(tmp, _SO)
+    os.replace(tmp, so)
 
 
 def _load():
@@ -81,12 +92,10 @@ def _load():
         if _build_failed is not None:
             raise RuntimeError(_build_failed)
         try:
-            if not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-            ):
-                _build_so()
-            spec = importlib.util.spec_from_file_location("_fastdecode", _SO)
+            so = _so_path()  # tpl: disable=TPL102(one-time native-module init; _load_lock exists precisely to serialize it, the source hash included)
+            if not os.path.exists(so):
+                _build_so(so)
+            spec = importlib.util.spec_from_file_location("_fastdecode", so)
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)  # tpl: disable=TPL003(one-time native-module load; _load_lock exists precisely to serialize this init)
             _mod = mod

@@ -148,15 +148,10 @@ class Oracle:
         tids = _np(self.nodes.taint_ids)          # [N, TN]
         effect = _np(self.snap.taint_effect)      # [VT]
         tol = _np(self.pods.tolerated)[p]         # [VT]
-        N = tids.shape[0]
-        ok = np.ones(N, bool)
-        for n in range(N):
-            for t in tids[n]:
-                if t < 0:
-                    continue
-                if effect[t] in (EFFECT_NO_SCHEDULE, EFFECT_NO_EXECUTE) and not tol[t]:
-                    ok[n] = False
-        return ok
+        t = np.maximum(tids, 0)
+        hard = np.isin(effect, (EFFECT_NO_SCHEDULE, EFFECT_NO_EXECUTE))
+        repels = (tids >= 0) & hard[t] & ~tol[t]
+        return ~repels.any(axis=1)
 
     def node_affinity_ok(self, p: int) -> np.ndarray:
         sat = self.atom_sat_nodes()                      # [A, N]
@@ -851,6 +846,9 @@ def validate_assignment(snap: ClusterSnapshot, cfg: EngineConfig,
     ia_fn = lambda o, p, on, op: o.interpod_ok_and_raw(p, on, op)[0]
     sym_fn = lambda o, p, on, op: o.symmetric_anti_ok(p, on, op)
 
+    # No signatures = no spread / inter-pod / symmetric-anti term on any
+    # pod or member, so the O(placed^2) pairwise checks are vacuous.
+    pairwise = snap.sigs.key.shape[0] > 0
     for p, n in placed:
         if not _np(nodes.valid)[n]:
             out.append(f"pod {p}: placed on invalid node {n}")
@@ -863,6 +861,8 @@ def validate_assignment(snap: ClusterSnapshot, cfg: EngineConfig,
             out.append(f"pod {p}: node {n} has untolerated taint")
         if not ora.node_affinity_ok(p)[n]:
             out.append(f"pod {p}: node {n} fails required node affinity")
+        if not pairwise:
+            continue
         if commit_key is None:
             others = [(q, m) for q, m in placed if q != p]
         else:

@@ -29,33 +29,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
-
-try:  # jax >= 0.6: public top-level name, check_vma kwarg
-    from jax import shard_map
-
-    # Native shard_map handles meshes with axes the specs don't
-    # mention (replication over 'n') correctly.
-    SHARD_MAP_2D_MESH_OK = True
-except ImportError:  # 0.4.x (this image): experimental namespace,
-    # and the replication-check kwarg is spelled check_rep there.
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    # KNOWN LIMITATION of the 0.4.x experimental shard_map: on a mesh
-    # with a second ('n') axis > 1 that the specs treat as replicated,
-    # the ppermute ring mis-routes and the counts come back wrong
-    # (verified empirically: every (ndev, 1) mesh is bit-exact vs the
-    # dense path, every (p, n>1) mesh diverges, with or without
-    # check_rep). 1D 'p' rings — the layout the ring path exists for —
-    # are unaffected; 2D-mesh ring tests skip on this flag.
-    SHARD_MAP_2D_MESH_OK = False
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map_exp(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
 
 from tpusched.kernels.atoms import gather_term_sat
 from tpusched.kernels.pairwise import ns_scope_ok

@@ -2496,13 +2496,12 @@ def make_server(
 def serve(address: str = "127.0.0.1:50051", config: EngineConfig | None = None,
           audit_path: str | None = None, watchdog_s: float = WATCHDOG_S,
           explain: bool = False, ledger_jsonl: str | None = None,
-          buckets: Buckets | None = None, prewarm: bool = False,
-          compile_cache: str | None = None):
+          buckets: Buckets | None = None, prewarm: bool = False):
     """Blocking entry point: python -m tpusched.rpc.server"""
     # Persistent XLA cache first (PR 18): a restarted sidecar then
     # reloads its programs instead of recompiling them — prewarm still
     # traces each class, but the trace hits the on-disk cache.
-    shapeclass.enable_persistent_cache(compile_cache)
+    shapeclass.enable_persistent_cache()
     audit = open(audit_path, "a") if audit_path else None
     server, port, svc = make_server(address, config, buckets=buckets,
                                     audit_stream=audit,
@@ -2543,11 +2542,6 @@ if __name__ == "__main__":
                     help="trace the full shape-class registry at boot "
                          "(PR 18: zero request-path compiles afterward; "
                          "needs --buckets)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory "
-                         "(default: $TPUSCHED_COMPILE_CACHE when set) — "
-                         "a restarted sidecar reloads programs instead "
-                         "of recompiling")
     args = ap.parse_args()
     cfg = None
     if args.config:
@@ -2563,4 +2557,4 @@ if __name__ == "__main__":
     serve(args.address, cfg, audit_path=args.audit,
           watchdog_s=args.watchdog_s, explain=args.explain,
           ledger_jsonl=args.ledger_jsonl, buckets=bk,
-          prewarm=args.prewarm, compile_cache=args.compile_cache)
+          prewarm=args.prewarm)

@@ -46,9 +46,11 @@ from tpusched.config import Buckets, EngineConfig
 CAUSE_SERVE = "serve"
 CAUSE_PREWARM = "prewarm"
 
-# Env var honored by enable_persistent_cache(): point it at a directory
-# shared between bench/CI rounds and round N+1 skips round N's compiles.
-CACHE_ENV = "TPUSCHED_COMPILE_CACHE"
+# Set from outside, JAX reads this itself: enable_persistent_cache()
+# then places no cache directory of its own.
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".xla_cache")
 
 REGISTRY_VERSION = 1
 
@@ -308,29 +310,20 @@ def prewarm_records(
     return nodes, pods, running
 
 
-def enable_persistent_cache(path: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at `path` (or the
-    TPUSCHED_COMPILE_CACHE env var when unset). Returns the directory in
-    effect, or None when neither is set (no-op — in-process jit caches
-    are unaffected either way). The thresholds are dropped to zero so
-    even sub-second CPU compiles persist: this repo's round-over-round
-    CI diffing wants round N+1's compile_count_total at ~0, not just the
-    big kernels cached."""
-    path = path if path is not None else os.environ.get(CACHE_ENV)
-    if not path:
-        return None
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+    Where $JAX_COMPILATION_CACHE_DIR is set, JAX already reads it and
+    this sets no directory; otherwise the cache goes to
+    <checkout>/.xla_cache (gitignored). The thresholds are dropped to
+    zero so even sub-second compiles persist. Call before the first
+    compile: JAX fixes the cache at its first use."""
     import jax  # tpl: disable=TPL001(optional dep: this module is stdlib-only so tools/check.py can reason about registries without jax; only cache wiring needs it)
 
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    for flag, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(flag, val)  # type: ignore[arg-type]
-        except Exception:
-            # Older jax spells the thresholds differently; the cache dir
-            # alone still persists the expensive programs.
-            pass
-    return str(path)
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = DEFAULT_CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path

@@ -1,10 +1,8 @@
 """Wire ledger (ISSUE 19): per-cycle round-trip decomposition.
 
-BENCH_r04/r05 showed the solver is no longer the bottleneck — fast
-solve p50 is ~152 ms at 10k x 5k while the measured transport RTT is
-~100-120 ms — yet the repo's only wire number was a single
-process-global p50 measured once at bench startup. This module is the
-PR 13 move applied to the transport: every client<->server cycle emits
+The repo's only wire number used to be a single process-global p50
+measured once at bench startup. This module is the PR 13 move applied
+to the transport: every client<->server cycle emits
 ONE schema-validated `WireRecord` that decomposes the full round trip
 into budgeted components, so ROADMAP item 2 (streaming wire +
 on-device response pack) has a baseline to beat per component instead
