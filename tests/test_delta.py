@@ -42,6 +42,23 @@ def _cluster_msg(n_pods=8, n_nodes=4, seed=0):
     return nodes, pods, running
 
 
+@pytest.mark.parametrize("named", [False, True])
+def test_delta_walk_counts_the_records_examined(named):
+    """`walk` reports what the diff's loops examined: every record of the
+    new snapshot, then every base record scanned for removals — with or
+    without `changed` naming the churn."""
+    nodes, pods, running = _cluster_msg()
+    store = SnapshotStore(snapshot_to_proto(nodes, pods, running))
+    pods2 = [p for p in pods if p["name"] != "p0"]
+    new = snapshot_to_proto(nodes, pods2, running)
+    walk = []
+    delta = delta_between(store, new, "snap-0",
+                          changed={"p0"} if named else None, walk=walk)
+    assert list(delta.remove_pods) == ["p0"]
+    assert walk == [len(nodes) * 2 + len(pods2) + len(pods)
+                    + len(running) * 2]
+
+
 def test_store_delta_roundtrip():
     """delta_between(prev, new) applied to prev's store recomposes new
     exactly (record sets keyed by name)."""
@@ -121,7 +138,8 @@ def test_delta_session_over_wire(sidecar):
     msg2 = snapshot_to_proto(nodes, pods2, running2)
     resp2 = sess.assign(msg2)
     assert sess.delta_sends == 1, "second cycle must ride the delta path"
-    assert sess.bytes_sent < sess.bytes_full_equiv, "delta must be smaller"
+    full_bytes = msg1.ByteSize() + msg2.ByteSize()
+    assert sess.bytes_sent < full_bytes, "delta must be smaller"
 
     cfg = EngineConfig(mode="fast")
     snap, meta = snapshot_from_proto(msg2, cfg)

@@ -1,0 +1,146 @@
+"""Stage spans inside the delta cycle: the client's own stages
+(client.check / client.diff / client.remember), the sidecar's byte-store
+step (store.advance) and the delta apply's stages (apply.records /
+apply.encode / apply.order / apply.scatter under delta.apply), driven
+through an in-process sidecar and a DeltaSession at a small size."""
+
+import pytest
+
+from tpusched import EngineConfig, trace
+from tpusched.rpc.client import DeltaSession, SchedulerClient
+from tpusched.rpc.codec import snapshot_to_proto
+from tpusched.rpc.server import make_server
+
+CLIENT = ("client.check", "client.diff", "client.remember")
+APPLY = ("apply.records", "apply.encode", "apply.order", "apply.scatter")
+NEW = CLIENT + ("store.advance",) + APPLY
+ATTRS = {"client.diff": {"visited", "shipped"}}
+EPS = 2e-3   # wall start + perf_counter duration: a clock-mix epsilon
+
+
+def _snapshot(cycle: int):
+    """Four nodes, running pods, pending pods; each cycle binds one pod,
+    adds one and bumps a node, so the apply reorders rows."""
+    nodes = [dict(name=f"n{j}",
+                  allocatable={"cpu": 8000.0 + (cycle if j == 0 else 0),
+                               "memory": float(32 << 30)})
+             for j in range(4)]
+    pods = [dict(name=f"p{j}", requests={"cpu": 200.0})
+            for j in range(cycle, 6 + cycle)]
+    running = [dict(name=f"r{j}", node=f"n{j % 4}",
+                    requests={"cpu": 100.0}) for j in range(3 + cycle)]
+    return snapshot_to_proto(nodes, pods, running)
+
+
+def _drive(col):
+    """A full send then two delta cycles; the spans of each cycle."""
+    server, port, svc = make_server("127.0.0.1:0",
+                                    config=EngineConfig(mode="fast"),
+                                    tracer=col)
+    server.start()
+    client = SchedulerClient(f"127.0.0.1:{port}", tracer=col)
+    sess = DeltaSession(client)
+    per_cycle = []
+    try:
+        for k in range(3):
+            col.clear()
+            sess.assign(_snapshot(k), changed=None if k == 0 else
+                        {"n0", f"p{k - 1}", f"p{5 + k}", f"r{2 + k}"},
+                        packed_ok=True)
+            per_cycle.append(col.spans())
+        assert (sess.full_sends, sess.delta_sends) == (1, 2)
+        assert svc.session_hits + svc.session_seeds == 2
+    finally:
+        client.close()
+        server.stop(0)
+        svc.close()
+    return per_cycle
+
+
+def _end(s):
+    return s.t_wall + s.dur_s
+
+
+def test_delta_cycles_carry_the_stage_spans(thread_leak_check):
+    col = trace.TraceCollector(seed=23)
+    full, *deltas = _drive(col)
+    assert {"client.remember"} <= {s.name for s in full}
+    assert not {"client.diff", "store.advance"} & {s.name for s in full}
+    for k, spans in enumerate(deltas, 1):
+        by_name = {}
+        for s in spans:
+            by_name.setdefault(s.name, []).append(s)
+        for name in NEW:
+            assert name in by_name, (name, sorted(by_name))
+            attrs = {k for s in by_name[name] for k in s.attrs}
+            assert attrs == ATTRS.get(name, set()), (name, attrs)
+        # The diff's loops examine every record of the new snapshot and
+        # of the base (4 nodes, 6 pods, 3 + k running pods on each side).
+        diff = by_name["client.diff"][0].attrs
+        assert diff["visited"] == (4 + 6 + 3 + k) + (4 + 6 + 2 + k)
+        assert 0 < diff["shipped"] < diff["visited"]
+        # One trace per cycle: the client's stages, the request and every
+        # sidecar span share the id the client minted.
+        rid = by_name["client.check"][0].trace_id
+        assert rid
+        for name in NEW + ("client.send", "server.Assign", "delta.apply"):
+            assert {s.trace_id for s in by_name[name]} == {rid}, name
+        # The apply's stages nest in delta.apply; store.advance is a
+        # stage of the request itself.
+        (apply,) = by_name["delta.apply"]
+        (root,) = by_name["server.Assign"]
+        for name in APPLY:
+            for s in by_name[name]:
+                assert s.parent_id == apply.span_id, name
+        assert by_name["store.advance"][0].parent_id == root.span_id
+        # The client's stages are flat and lie outside the send.
+        (send,) = by_name["client.send"]
+        client_ids = {s.span_id for n in CLIENT for s in by_name[n]}
+        for name in CLIENT:
+            for s in by_name[name]:
+                assert s.parent_id not in client_ids
+                assert (_end(s) <= send.t_wall + EPS
+                        or s.t_wall >= _end(send) - EPS), name
+        # None of the new spans encloses the cycle: the cycle runs from
+        # the client's first stage to its last.
+        lo = min(s.t_wall for s in spans if s.name in CLIENT)
+        hi = max(_end(s) for s in spans if s.name in CLIENT)
+        for name in NEW:
+            for s in by_name[name]:
+                assert not (s.t_wall <= lo and _end(s) >= hi), name
+                assert s.dur_s < hi - lo, name
+        # The apply's stages account for the apply.
+        staged = sum(s.dur_s for n in APPLY for s in by_name[n])
+        assert staged <= apply.dur_s + EPS
+
+
+def test_disabled_collector_records_no_stage_span(thread_leak_check):
+    trace.DEFAULT.clear()
+    col = trace.TraceCollector(seed=23, enabled=False)
+    per_cycle = _drive(col)
+    assert all(spans == [] for spans in per_cycle)
+    leaked = {s.name for s in trace.DEFAULT.spans()}
+    assert not leaked & set(NEW), leaked
+
+
+@pytest.mark.parametrize("cat", ["device", "server"])
+def test_laps_record_back_to_back_stages(cat):
+    col = trace.TraceCollector(seed=1)
+    with col.span("outer", trace_id="t") as outer:
+        laps = col.laps(cat)
+        laps.lap("a")
+        laps.lap("b")
+        laps.lap("a")
+        laps.close()
+        laps.close()
+    *stages, last = col.spans()
+    assert [s.name for s in stages] == ["a", "b", "a"]
+    assert last.name == "outer"
+    for s in stages:
+        assert s.parent_id == outer.span_id and s.trace_id == "t"
+        assert s.cat == cat and s.attrs == {}
+    a1, b, a2 = stages
+    assert a1.t_wall <= b.t_wall <= a2.t_wall
+    off = trace.TraceCollector(enabled=False).laps(cat)
+    off.lap("a")
+    off.close()

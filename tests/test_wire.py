@@ -161,6 +161,39 @@ def test_assemble_stitches_skewed_server_and_reconstructs_wall():
     wiring.validate_record(wiring.record_dict(rec))
 
 
+def test_assemble_counts_store_advance_and_skips_nested_spans():
+    """store.advance is an exclusive serving phase: it is its own
+    component, not server.other. The apply.* spans nested in
+    delta.apply and the client's own stage spans are not components
+    (summing them beside their parent would count their time twice)."""
+    assert "store.advance" in wiring.SERVER_STAGES
+    assert not any(s.startswith(("apply.", "client."))
+                   for s in wiring.SERVER_STAGES)
+    rid = "cycle-delta"
+    spans = [
+        _span(rid, "client.check", "client", 99.90, 0.02, 1),
+        _span(rid, "client.diff", "client", 99.92, 0.07, 2),
+        _span(rid, "client.send", "client", 100.0, 0.5, 3),
+        _span(rid, "server.Assign", "server", 100.05, 0.4, 4),
+        _span(rid, "store.advance", "server", 100.05, 0.01, 5, parent=4),
+        _span(rid, "delta.apply", "server", 100.06, 0.1, 6, parent=4),
+        _span(rid, "apply.records", "device", 100.06, 0.03, 7, parent=6),
+        _span(rid, "apply.order", "device", 100.09, 0.06, 8, parent=6),
+        _span(rid, "fetch.join", "server", 100.2, 0.2, 9, parent=4),
+        _span(rid, "client.remember", "client", 100.5, 0.01, 10),
+    ]
+    rec = wiring.assemble(rid, "Assign", spans,
+                          wiring.ClockOffsetEstimator())
+    assert rec is not None and rec.stitched
+    assert rec.stages["store.advance"] == pytest.approx(0.01)
+    assert rec.stages["delta.apply"] == pytest.approx(0.1)
+    assert not any(k.startswith(("apply.", "client.")) for k in rec.stages)
+    # Root residue: 0.4 - (0.01 + 0.1 + 0.2).
+    assert rec.stages["server.other"] == pytest.approx(0.09, abs=1e-6)
+    assert wiring.COMPONENT_ORDER.index("store.advance") \
+        < wiring.COMPONENT_ORDER.index("delta.apply")
+
+
 def test_assemble_pairs_the_retry_attempt_with_its_own_root():
     """Two sends under one rid (first errored before reaching the
     server): the lone root pairs with the attempt whose window fits

@@ -398,6 +398,10 @@ class DeviceSnapshot:
     ) -> ApplyStats:
         if self._device is None:
             raise ValueError("DeviceSnapshot: full_load() first")
+        # Stage spans under the caller's open span (the sidecar's
+        # delta.apply): records / encode / order / scatter, per stretch.
+        laps = (self.tracer or tracing.DEFAULT).laps("device")
+        laps.lap("apply.records")
         upsert_nodes = [dict(r) for r in upsert_nodes]
         upsert_pods = [dict(r) for r in upsert_pods]
         upsert_running = [dict(r) for r in upsert_running]
@@ -456,11 +460,13 @@ class DeviceSnapshot:
         try:
             stats = self._apply_incremental(
                 upsert_nodes, remove_nodes, upsert_pods, remove_pods,
-                upsert_running, remove_running,
+                upsert_running, remove_running, laps,
             )
         except _NeedsRebuild as e:
+            laps.close()
             stats = self._rebuild(e.reason)
         except Exception:  # noqa: BLE001 — heal, then let tests catch it
+            laps.close()
             logging.getLogger("tpusched.device_state").warning(
                 "incremental delta apply failed; rebuilding this "
                 "lineage:\n%s", traceback.format_exc(limit=4),
@@ -470,12 +476,14 @@ class DeviceSnapshot:
         return stats
 
     def _apply_incremental(self, upsert_nodes, remove_nodes, upsert_pods,
-                           remove_pods, upsert_running, remove_running
-                           ) -> ApplyStats:
+                           remove_pods, upsert_running, remove_running,
+                           laps) -> ApplyStats:
         st = self._state
         intr = st.interner
         bk = st.buckets
         cfg = self.config
+        # O(churn): normalize, intern and check the churned records.
+        laps.lap("apply.encode")
 
         # Row-count capacity.
         if (len(self._pods) > bk.pods or len(self._nodes) > bk.nodes
@@ -637,6 +645,8 @@ class DeviceSnapshot:
             if old_node is not None:
                 node_churn.add(old_node)
         node_churn &= set(self._nodes)
+        # Name-order upkeep: every pass below walks the whole cluster.
+        laps.lap("apply.order")
         self._refresh_prev_maps()
 
         new_node_order = sorted(self._nodes)
@@ -686,6 +696,7 @@ class DeviceSnapshot:
         for name in new_run_order:
             run_by_node.setdefault(self._norm_running[name]["node"],
                                    []).append(name)
+        laps.lap("apply.encode")
         for nm in node_churn:
             i = st.node_index[nm]
             _fill_node_row(st.nodes_np, i, self._norm_nodes[nm], intr, cfg)
@@ -729,6 +740,7 @@ class DeviceSnapshot:
             )
 
         # Device updates: permutation gathers, then row scatters.
+        laps.lap("apply.scatter")
         h2d = 0
         rows_written = 0
         dev = self._device
@@ -795,6 +807,7 @@ class DeviceSnapshot:
             atoms=atoms_dev, sigs=sigs_dev, group_min_member=group_dev,
             pdb_allowed=pdb_dev,
         ))
+        laps.lap("apply.order")
         self._node_order = new_node_order
         self._pod_order = new_pod_order
         self._run_order = new_run_order
@@ -822,6 +835,7 @@ class DeviceSnapshot:
                                               key=lambda kv: kv[1])],
             running_names=list(new_run_order),
         )
+        laps.close()
         self.delta_updates += 1
         self.h2d_bytes_last = h2d
         self.h2d_bytes_total += h2d

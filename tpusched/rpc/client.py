@@ -445,20 +445,22 @@ class SchedulerClient:
         )
 
     def score_batch(self, snapshot: pb.ClusterSnapshot, *,
-                    packed_ok: bool = False,
-                    top_k: int = 0) -> pb.ScoreResponse:
+                    packed_ok: bool = False, top_k: int = 0,
+                    request_id: str = "") -> pb.ScoreResponse:
         return self._call(
             self._score,
             pb.ScoreRequest(snapshot=snapshot, packed_ok=packed_ok,
-                            top_k=top_k),
+                            top_k=top_k, request_id=request_id),
             rpc="ScoreBatch",
         )
 
     def assign(self, snapshot: pb.ClusterSnapshot, *,
-               packed_ok: bool = False) -> pb.AssignResponse:
+               packed_ok: bool = False,
+               request_id: str = "") -> pb.AssignResponse:
         return self._call(
             self._assign,
-            pb.AssignRequest(snapshot=snapshot, packed_ok=packed_ok),
+            pb.AssignRequest(snapshot=snapshot, packed_ok=packed_ok,
+                             request_id=request_id),
             rpc="Assign",
         )
 
@@ -493,11 +495,12 @@ class SchedulerClient:
         )
 
     def score_batch_delta(self, delta: pb.SnapshotDelta, *,
-                          packed_ok: bool = False,
-                          top_k: int = 0) -> pb.ScoreResponse:
+                          packed_ok: bool = False, top_k: int = 0,
+                          request_id: str = "") -> pb.ScoreResponse:
         return self._call(
             self._score,
-            pb.ScoreRequest(delta=delta, packed_ok=packed_ok, top_k=top_k),
+            pb.ScoreRequest(delta=delta, packed_ok=packed_ok, top_k=top_k,
+                            request_id=request_id),
             rpc="ScoreBatch",
         )
 
@@ -524,10 +527,12 @@ class SchedulerClient:
         )
 
     def assign_delta(self, delta: pb.SnapshotDelta, *,
-                     packed_ok: bool = False) -> pb.AssignResponse:
+                     packed_ok: bool = False,
+                     request_id: str = "") -> pb.AssignResponse:
         return self._call(
             self._assign,
-            pb.AssignRequest(delta=delta, packed_ok=packed_ok),
+            pb.AssignRequest(delta=delta, packed_ok=packed_ok,
+                             request_id=request_id),
             rpc="Assign",
         )
 
@@ -631,37 +636,57 @@ class DeltaSession:
         self.delta_sends = 0
         self.fallbacks = 0
         self.bytes_sent = 0
-        self.bytes_full_equiv = 0
 
     def _call(self, snapshot: pb.ClusterSnapshot, send_full, send_delta,
               changed: "set[str] | None" = None):
-        full_bytes = snapshot.ByteSize()
-        self.bytes_full_equiv += full_bytes
+        """One cycle. Its client-side stages are flat spans (check,
+        diff, remember) under the cycle's trace id, which the request
+        then carries, so the sidecar's spans join the same trace; none
+        encloses the send."""
+        tracer = self.client.tracer
+        ctx = tracer.current()
+        rid = ctx[0] if ctx is not None and ctx[0] else \
+            tracer.new_trace_id()
+        safe = False
         if (
             self._base is not None
             and self._base_id is not None
             and self._skip_delta == 0
+        ):
             # The NEW snapshot must itself be delta-safe: the server's
             # name-keyed store would silently collapse unnamed/duplicate
             # records arriving as delta upserts and solve a corrupted
             # snapshot for this cycle. (_remember only drops the base
             # for the NEXT cycle — one cycle too late.)
-            and codec.delta_safe(snapshot)
-        ):
+            with tracer.span("client.check", cat="client", trace_id=rid):
+                safe = codec.delta_safe(snapshot)
+        if safe:
             new_bytes = codec.SnapshotStore()
-            delta = codec.delta_between(
-                self._base, snapshot, self._base_id, new_bytes=new_bytes,
-                changed=changed,
-            )
-            self._seq += 1
-            delta.lineage_id = self._lineage_id
-            delta.seq = self._seq
-            self.bytes_sent += delta.ByteSize()  # transmitted even on reject
+            walk: "list[int]" = []
+            with tracer.span("client.diff", cat="client",
+                             trace_id=rid) as sp:
+                delta = codec.delta_between(
+                    self._base, snapshot, self._base_id,
+                    new_bytes=new_bytes, changed=changed, walk=walk,
+                )
+                self._seq += 1
+                delta.lineage_id = self._lineage_id
+                delta.seq = self._seq
+                delta_bytes = delta.ByteSize()
+                # Records the diff examined, and records it shipped.
+                sp.attrs.update(
+                    visited=walk[0],
+                    shipped=len(delta.upsert_nodes) + len(delta.upsert_pods)
+                    + len(delta.upsert_running) + len(delta.remove_nodes)
+                    + len(delta.remove_pods) + len(delta.remove_running))
+            self.bytes_sent += delta_bytes  # transmitted even on reject
             try:
-                resp = send_delta(delta)
+                resp = send_delta(delta, rid)
                 self.delta_sends += 1
                 self._consec_fallbacks = 0
-                self._remember(snapshot, resp.snapshot_id, new_bytes)
+                with tracer.span("client.remember", cat="client",
+                                 trace_id=rid):
+                    self._remember(snapshot, resp.snapshot_id, new_bytes)
                 return resp
             except grpc.RpcError as e:
                 if e.code() != grpc.StatusCode.FAILED_PRECONDITION:
@@ -676,23 +701,27 @@ class DeltaSession:
                 # Minted trace id: the span AND the full send under it
                 # (which inherits the id via _stamp) group as one trace
                 # in Debugz — trace_id=None here would record untraced.
-                with self.client.tracer.span(
+                with tracer.span(
                     "client.resync", cat="client",
-                    trace_id=self.client.tracer.new_trace_id(),
+                    trace_id=tracer.new_trace_id(),
                     lineage=self._lineage_id, seq=self._seq,
                 ):
-                    resp = send_full(snapshot)
+                    resp = send_full(snapshot, "")
                 self.full_sends += 1
-                self.bytes_sent += full_bytes
+                self.bytes_sent += snapshot.ByteSize()
                 # delta_safe already verified this cycle (guard above).
-                self._remember(snapshot, resp.snapshot_id, verified=True)
+                with tracer.span("client.remember", cat="client",
+                                 trace_id=rid):
+                    self._remember(snapshot, resp.snapshot_id,
+                                   verified=True)
                 return resp
         elif self._skip_delta > 0:
             self._skip_delta -= 1
-        resp = send_full(snapshot)
+        resp = send_full(snapshot, rid)
         self.full_sends += 1
-        self.bytes_sent += full_bytes
-        self._remember(snapshot, resp.snapshot_id)
+        self.bytes_sent += snapshot.ByteSize()
+        with tracer.span("client.remember", cat="client", trace_id=rid):
+            self._remember(snapshot, resp.snapshot_id)
         return resp
 
     def _remember(self, snapshot: pb.ClusterSnapshot, sid: str,
@@ -725,8 +754,9 @@ class DeltaSession:
         diff O(churn) — see codec.delta_between."""
         return self._call(
             snapshot,
-            lambda s: self.client.assign(s, **kw),
-            lambda d: self.client.assign_delta(d, **kw),
+            lambda s, rid: self.client.assign(s, request_id=rid, **kw),
+            lambda d, rid: self.client.assign_delta(d, request_id=rid,
+                                                    **kw),
             changed=changed,
         )
 
@@ -735,8 +765,10 @@ class DeltaSession:
                     **kw) -> pb.ScoreResponse:
         return self._call(
             snapshot,
-            lambda s: self.client.score_batch(s, **kw),
-            lambda d: self.client.score_batch_delta(d, **kw),
+            lambda s, rid: self.client.score_batch(s, request_id=rid,
+                                                   **kw),
+            lambda d, rid: self.client.score_batch_delta(
+                d, request_id=rid, **kw),
             changed=changed,
         )
 

@@ -361,7 +361,8 @@ def _ser(rec) -> bytes:
 def delta_between(prev: SnapshotStore, msg: pb.ClusterSnapshot,
                   base_id: str,
                   new_bytes: SnapshotStore | None = None,
-                  changed: "set[str] | None" = None) -> pb.SnapshotDelta:
+                  changed: "set[str] | None" = None,
+                  walk: "list[int] | None" = None) -> pb.SnapshotDelta:
     """Client-side diff: the SnapshotDelta turning `prev` into `msg`.
     Record equality by serialized bytes. `prev` values may be messages
     or pre-serialized bytes (DeltaSession stores bytes so that a caller
@@ -380,14 +381,21 @@ def delta_between(prev: SnapshotStore, msg: pb.ClusterSnapshot,
     work (~100 ms at 10k x 5k). Additions and removals are still
     detected by name regardless. CONTRACT: a caller that mutates a
     record without naming it here ships a stale record and the sidecar
-    solves a stale snapshot — name everything you touch."""
+    solves a stale snapshot — name everything you touch.
+
+    walk: optional list; when given, the number of records the diff's
+    loops examined (new records, then base records scanned for
+    removals) is appended to it — the scan the client pays per cycle."""
     delta = pb.SnapshotDelta(base_id=base_id)
     if changed is not None and not isinstance(changed, set):
         changed = set(changed)
+    walked = 0
 
     def diff(prev_d, coll, upserts, removes, out_d):
+        nonlocal walked
         new_names = set()
-        for rec in coll:
+        n = 0
+        for n, rec in enumerate(coll, 1):
             new_names.add(rec.name)
             old = prev_d.get(rec.name)
             if (changed is not None and old is not None
@@ -400,7 +408,11 @@ def delta_between(prev: SnapshotStore, msg: pb.ClusterSnapshot,
                 out_d[rec.name] = raw
             if old is None or _ser(old) != raw:
                 upserts.append(rec)
-        removes.extend(k for k in prev_d if k not in new_names)
+        m = 0
+        for m, k in enumerate(prev_d, 1):
+            if k not in new_names:
+                removes.append(k)
+        walked += n + m
 
     nb = new_bytes
     diff(prev.nodes, msg.nodes, delta.upsert_nodes, delta.remove_nodes,
@@ -409,6 +421,8 @@ def delta_between(prev: SnapshotStore, msg: pb.ClusterSnapshot,
          nb.pods if nb else None)
     diff(prev.running, msg.running, delta.upsert_running,
          delta.remove_running, nb.running if nb else None)
+    if walk is not None:
+        walk.append(walked)
     return delta
 
 

@@ -657,6 +657,10 @@ class DeviceSession:
     def apply_delta(self, base_id: str, delta: "pb.SnapshotDelta",
                     new_sid: str):
         """Advance to base_id + delta. base_id must be one of keys()."""
+        # Record-store upkeep before the device apply: the first
+        # apply.records stretch under the sidecar's delta.apply.
+        laps = (self.device.tracer or tracing.DEFAULT).laps("device")
+        laps.lap("apply.records")
         if base_id == self._cur_sid and base_id != self._pin_sid:
             # Chain step: the client committed to the current state —
             # re-pin here (shallow copies; record dicts are replaced,
@@ -691,6 +695,7 @@ class DeviceSession:
                     ups.append(pin[name])
                 else:
                     rms.append(name)
+        laps.close()
         self.last_stats = self.device.apply(
             upsert_nodes=up_n, remove_nodes=rm_n,
             upsert_pods=up_p, remove_pods=rm_p,
@@ -1454,28 +1459,29 @@ class SchedulerService:
                     grpc.StatusCode.INVALID_ARGUMENT,
                     "delta request carries no base_id",
                 )
-            self._check_delta_upserts(request.delta)
-            with self._store_lock:
-                base = self._stores.get(base_id)
-                if base is not None:
-                    # True-LRU refresh: a hit keeps the base alive while
-                    # unrelated sessions churn the cap.
-                    self._stores.pop(base_id)
-                    self._stores[base_id] = base
-            if base is None:
-                raise _Abort(
-                    grpc.StatusCode.FAILED_PRECONDITION,
-                    f"unknown snapshot base_id {base_id!r}",
+            d = request.delta
+            with self._trace.span("store.advance", cat="server"):
+                self._check_delta_upserts(d)
+                with self._store_lock:
+                    base = self._stores.get(base_id)
+                    if base is not None:
+                        # True-LRU refresh: a hit keeps the base alive
+                        # while unrelated sessions churn the cap.
+                        self._stores.pop(base_id)
+                        self._stores[base_id] = base
+                if base is None:
+                    raise _Abort(
+                        grpc.StatusCode.FAILED_PRECONDITION,
+                        f"unknown snapshot base_id {base_id!r}",
+                    )
+                store = base.copy()
+                store.apply_delta(d)
+                # Replication op (round 11): ship the delta verbatim;
+                # the standby re-applies it against its own copy of
+                # base_id and registers the result under this very sid.
+                sid = self._register_store(
+                    store, "delta", d.SerializeToString(), base_id=base_id,
                 )
-            store = base.copy()
-            store.apply_delta(request.delta)
-            # Replication op (round 11): ship the delta verbatim; the
-            # standby re-applies it against its own copy of base_id and
-            # registers the result under this very sid.
-            sid = self._register_store(
-                store, "delta", request.delta.SerializeToString(),
-                base_id=base_id,
-            )
             t0 = time.perf_counter()
             seeding = False
             session = None

@@ -102,6 +102,50 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+class _NoopLaps:
+    """Disabled-path twin of `Laps`: shared, records nothing."""
+
+    __slots__ = ()
+
+    def lap(self, name: str) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
+
+_NOOP_LAPS = _NoopLaps()
+
+
+class Laps:
+    """Back-to-back stage spans over straight-line code too long to
+    indent under one `with` per stage: `lap(name)` ends the running
+    stage and starts `name`, `close()` ends the last one. Each stage is
+    recorded when it ends, through `TraceCollector.record`, under this
+    thread's innermost open span. A name may recur (one stage whose
+    work runs in two stretches)."""
+
+    __slots__ = ("_col", "_cat", "_name", "_t0")
+
+    def __init__(self, col: "TraceCollector", cat: str):
+        self._col = col
+        self._cat = cat
+        self._name: "str | None" = None
+        self._t0 = 0.0
+
+    def lap(self, name: str) -> None:
+        self.close()
+        self._name = name
+        self._t0 = time.perf_counter()
+
+    def close(self) -> None:
+        if self._name is None:
+            return
+        self._col.record(self._name, time.perf_counter() - self._t0,
+                         cat=self._cat)
+        self._name = None
+
+
 class _LiveSpan:
     """One open span; finishing (context exit) appends the immutable
     record to the collector ring."""
@@ -206,6 +250,13 @@ class TraceCollector:
         if not self.enabled:
             return _NOOP
         return _LiveSpan(self, name, cat, trace_id, int(parent_id), attrs)
+
+    def laps(self, cat: str = "server") -> "Laps | _NoopLaps":
+        """Consecutive stage spans (see `Laps`); a shared no-op when
+        disabled."""
+        if not self.enabled:
+            return _NOOP_LAPS
+        return Laps(self, cat)
 
     def record(self, name: str, dur_s: float = 0.0, cat: str = "event",
                ctx: "tuple[str, int] | None" = None,

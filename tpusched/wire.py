@@ -23,8 +23,8 @@ Three pieces:
     one congested sample never poisons the offset.
   * `assemble()` — joins one cycle's spans (the ledger does NOT
     re-instrument: client.serialize / client.send / client.retry /
-    client.join and the server stages spanned since PR 4 — gate.wait,
-    coalesce.wait, decode, delta.apply, dispatch, fetch.join (device
+    client.join and the server stages — gate.wait, coalesce.wait,
+    store.advance, decode, delta.apply, dispatch, fetch.join (device
     solve + D2H), reply.names, reply.pack) into a WireRecord. The two
     one-way gaps are offset-corrected: `send.gap` = client send start
     -> server root start (up transit + server ingress queue) and
@@ -38,7 +38,7 @@ Three pieces:
     p99 (non-interpolated covering-bucket bound) is attributed in
     order — payload well above the rolling byte p95 -> "bytes_burst";
     else the component group with the largest excess over its rolling
-    median: gate/coalesce waits -> "queue", serialize/decode/apply ->
+    median: gate/coalesce waits -> "queue", serialize/store/decode/apply ->
     "decode", gaps/fetch/reply -> "transfer"; else "unknown". Each
     anomaly bumps `scheduler_wire_anomalies_total{cause}`, fires the
     attached FlightRecorder with the attributed record, and — when
@@ -77,22 +77,27 @@ ANOMALY_CAUSES = ("bytes_burst", "queue", "decode", "transfer", "unknown")
 # mutually exclusive serving phases, so their sum stays <= the root
 # wall). fetch.join includes the device solve AND the D2H result fetch
 # (the engine's ordered fetch worker materializes inside it).
-SERVER_STAGES = ("gate.wait", "coalesce.wait", "decode", "delta.apply",
-                 "dispatch", "fetch.join", "reply.names", "reply.pack")
+# store.advance is the delta branch's byte-store step before the apply.
+# Spans nested inside a stage (delta.apply's apply.*) stay out: the sum
+# would count their time twice.
+SERVER_STAGES = ("gate.wait", "coalesce.wait", "store.advance", "decode",
+                 "delta.apply", "dispatch", "fetch.join", "reply.names",
+                 "reply.pack")
 
 # Cause-attribution groups (sentinel docstring). server.other is the
 # unattributed server residue: store.compose / session.seed / handler
 # glue — transfer-adjacent for attribution purposes because it moves
 # with the same H2D/device pressure fetch.join does.
 _QUEUE = ("gate.wait", "coalesce.wait")
-_DECODE = ("serialize", "decode", "delta.apply")
+_DECODE = ("serialize", "store.advance", "decode", "delta.apply")
 _TRANSFER = ("send.gap", "reply.gap", "fetch.join", "reply.names",
              "reply.pack", "server.other")
 
 # Canonical component order for rendering (statusz panel, the Perfetto
 # breakdown track, bench emission): request-path order.
 COMPONENT_ORDER = ("serialize", "send.gap", "retry.backoff", "gate.wait",
-                   "coalesce.wait", "decode", "delta.apply", "dispatch",
+                   "coalesce.wait", "store.advance", "decode",
+                   "delta.apply", "dispatch",
                    "fetch.join", "reply.names", "reply.pack",
                    "server.other", "reply.gap", "unknown")
 
