@@ -19,6 +19,7 @@ from tpusched.snapshot import (
     SnapshotBuilder,
     Toleration,
     TopologySpreadConstraint,
+    _snapshot_from_arrays,
 )
 
 
@@ -298,3 +299,200 @@ def test_running_pod_missing_node_raises(loaded):
     # State untouched: a rebuild-equality still holds.
     snap, _ = _fresh_build(nodes, pods, running, ds.meta.buckets)
     _assert_trees_equal(ds.snap, snap)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_reorder_equals_the_general_perm(seed):
+    """The churn-driven order merge gives the order a sort gives and the
+    permutation and vacated rows `_perm` derives from the two orders."""
+    rng = np.random.default_rng(seed)
+    old = sorted({f"k{int(v):05d}" for v in rng.integers(0, 99999, 300)})
+    gone = set(rng.choice(old, size=40, replace=False).tolist())
+    fresh = {f"k{int(v):05d}x" for v in rng.integers(0, 99999, 25)}
+    removed = sorted(old.index(nm) for nm in gone)
+    new, perm, pads, runs = DeviceSnapshot._reorder(
+        old, removed, sorted(fresh), 512)
+    assert new == sorted((set(old) - gone) | fresh)
+    want_perm, want_pads = DeviceSnapshot._perm(old, new, 512)
+    np.testing.assert_array_equal(perm, want_perm)
+    assert perm.dtype == want_perm.dtype and pads == want_pads
+    # The runs hold every surviving row once, old row -> new row.
+    for o, n, length in runs:
+        assert old[o:o + length] == new[n:n + length]
+    assert sum(length for *_, length in runs) == len(old) - len(gone)
+    assert DeviceSnapshot._reorder(old, [], [], 512) == (old, None, [], [])
+
+
+def _churn_cluster():
+    """_records' cluster plus a second gang and a second PDB, so churned
+    records can move between slots that never empty."""
+    nodes, pods, running = _records()
+    pods.append(dict(name="p99", requests={"cpu": 100.0},
+                     labels={"app": "web"}, pod_group="gang-b",
+                     pod_group_min_member=1))
+    running.append(dict(name="r99", node="n00", requests={"cpu": 200.0},
+                        labels={"app": "web"}, pdb_group="pdb-b",
+                        pdb_disruptions_allowed=2))
+    return ({r["name"]: r for r in nodes}, {r["name"]: r for r in pods},
+            {r["name"]: r for r in running})
+
+
+def _random_delta(rng, k, nodes, pods, running, base):
+    """One delta over the record dicts (mutated to the post-apply state).
+    Churned records reuse vocabulary the base cluster interned first, so
+    a fresh build must equal the delta-applied arrays."""
+    delta = dict(upsert_nodes=[], remove_nodes=[], upsert_pods=[],
+                 remove_pods=[], upsert_running=[], remove_running=[])
+    added_nodes = [n for n in nodes if n not in base[0]]
+    if k == 1 or (k > 3 and rng.random() < 0.3):
+        nm = f"n{int(rng.integers(0, 6)):02d}x{k}"
+        nodes[nm] = dict(name=nm, allocatable={
+            "cpu": float(rng.integers(4, 16) * 1000),
+            "memory": float(16 << 30)},
+            labels={"zone": "a", "disktype": "ssd"})
+        delta["upsert_nodes"].append(nodes[nm])
+    elif added_nodes and (k == 4 or rng.random() < 0.3):
+        gone = added_nodes[int(rng.integers(len(added_nodes)))]
+        del nodes[gone]
+        delta["remove_nodes"].append(gone)
+        for r in [r for r in running.values() if r["node"] == gone]:
+            if rng.random() < 0.5 and r["name"] not in base[2]:
+                del running[r["name"]]
+                delta["remove_running"].append(r["name"])
+            else:
+                running[r["name"]] = dict(r, node="n01")
+                delta["upsert_running"].append(running[r["name"]])
+    base_node = f"n{int(rng.integers(0, 6)):02d}"
+    nodes[base_node] = dict(nodes[base_node], allocatable={
+        "cpu": float(rng.integers(6, 12) * 1000), "memory": float(32 << 30)})
+    delta["upsert_nodes"].append(nodes[base_node])
+    live = sorted(nodes)
+    churned_r = ({r["name"] for r in delta["upsert_running"]}
+                 | set(delta["remove_running"]))
+    for j in range(int(rng.integers(1, 4))):     # bind
+        nm = f"r{int(rng.integers(0, 5)):02d}x{k}{j}"
+        pdb = rng.choice(["", "pdb-a", "pdb-b"])
+        running[nm] = dict(
+            name=nm, node=live[int(rng.integers(len(live)))],
+            requests={"cpu": float(rng.integers(1, 9) * 50)},
+            labels={"app": str(rng.choice(["web", "db", "cache"]))},
+            **({"pdb_group": str(pdb),
+                "pdb_disruptions_allowed": int(rng.integers(0, 3))}
+               if pdb else {}))
+        delta["upsert_running"].append(running[nm])
+        churned_r.add(nm)
+    for nm in sorted(running):                   # complete / move
+        if nm in churned_r:
+            continue
+        u = rng.random()
+        if u < 0.15 and nm not in base[2]:
+            del running[nm]
+            delta["remove_running"].append(nm)
+        elif u < 0.3:
+            running[nm] = dict(running[nm],
+                               node=live[int(rng.integers(len(live)))])
+            delta["upsert_running"].append(running[nm])
+    for j in range(int(rng.integers(1, 4))):     # arrive
+        nm = f"p{int(rng.integers(2, 14)):02d}x{k}{j}"
+        g = rng.choice(["", "gang-a", "gang-b"])
+        pods[nm] = dict(
+            name=nm, requests={"cpu": float(rng.integers(1, 9) * 50)},
+            labels={"app": str(rng.choice(["web", "db", "cache"]))},
+            priority=float(rng.integers(0, 100)), observed_avail=1.0,
+            **({"pod_group": str(g),
+                "pod_group_min_member": int(rng.integers(1, 4))}
+               if g else {}))
+        delta["upsert_pods"].append(pods[nm])
+    arrived = {p["name"] for p in delta["upsert_pods"]}
+    for nm in sorted(pods):                      # bind away / update
+        if nm in arrived:
+            continue
+        u = rng.random()
+        if u < 0.2 and nm not in base[1]:
+            del pods[nm]
+            delta["remove_pods"].append(nm)
+        elif u < 0.35 and nm not in base[1]:
+            g = rng.choice(["", "gang-a", "gang-b"])
+            rec = {k2: v for k2, v in pods[nm].items()
+                   if k2 not in ("pod_group", "pod_group_min_member")}
+            if g:
+                rec.update(pod_group=str(g), pod_group_min_member=2)
+            pods[nm] = rec
+            delta["upsert_pods"].append(rec)
+        elif u < 0.5:
+            pods[nm] = dict(pods[nm], priority=float(rng.integers(0, 100)))
+            delta["upsert_pods"].append(pods[nm])
+    return delta
+
+
+DERIVED = ("_run_by_node", "_run_node_name", "_pod_group_name",
+           "_run_pdb_key", "_group_members", "_pdb_members")
+
+
+def _mirror(ds):
+    st = ds._state
+    return _snapshot_from_arrays(st.nodes_np, st.pods_np, st.run_np,
+                                 st.tables)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_churn_upkeep_equals_rebuild(seed):
+    """Random deltas (binds, completions, moves between nodes, arrivals,
+    removals, updates, node inserts and removals, gangs and PDBs entered
+    and left): after each apply the orders, maps, mirror and device
+    arrays the delta path keeps up equal a from-scratch load of the same
+    records."""
+    rng = np.random.default_rng(seed)
+    nodes, pods, running = _churn_cluster()
+    base = (set(nodes), set(pods), set(running))
+    ds = DeviceSnapshot(EngineConfig(), Buckets.fit(64, 32, 64))
+    ds.full_load(nodes.values(), pods.values(), running.values())
+    node_moves = 0
+    for k in range(1, 9):
+        delta = _random_delta(rng, k, nodes, pods, running, base)
+        stats = ds.apply(**delta)
+        assert stats.path == "delta", stats.reason
+        assert 0 < stats.order_visited
+        node_moves += bool(delta["remove_nodes"]
+                           or len(delta["upsert_nodes"]) > 1)
+        assert ds._node_order == sorted(nodes)
+        assert ds._pod_order == sorted(pods)
+        assert ds._run_order == sorted(running)
+        ref = DeviceSnapshot(EngineConfig(), ds.meta.buckets)
+        ref.full_load(nodes.values(), pods.values(), running.values())
+        for attr in DERIVED:
+            assert getattr(ds, attr) == getattr(ref, attr), (k, attr)
+        assert ds._state.node_index == ref._state.node_index
+        assert ds.meta == ref.meta
+        _assert_trees_equal(_mirror(ds), _mirror(ref))
+        _assert_trees_equal(ds.snap, ref.snap)
+    assert node_moves >= 2 and ds.rebuilds == 0
+
+
+def test_order_upkeep_scales_with_churn_not_cluster():
+    """At ~20k running pods a 50-record delta examines O(churn × log n)
+    names: a whole-cluster pass in the upkeep would count ~20k."""
+    n_run = 20_000
+    nodes = [dict(name=f"n{i:03d}", allocatable={"cpu": 1e6})
+             for i in range(64)]
+    pods = [dict(name=f"p{i:03d}", requests={"cpu": 10.0})
+            for i in range(16)]
+    running = [dict(name=f"r{i:05d}", node=f"n{i % 64:03d}",
+                    requests={"cpu": 10.0}) for i in range(n_run)]
+    ds = DeviceSnapshot(EngineConfig())
+    ds.full_load(nodes, pods, running)
+    stats = ds.apply(
+        remove_running=[f"r{i:05d}" for i in range(0, n_run, 1000)],
+        upsert_running=[dict(name=f"r{i:05d}x", node="n005",
+                             requests={"cpu": 20.0})
+                        for i in range(500, n_run, 1000)],
+        upsert_pods=[dict(pods[i], requests={"cpu": 11.0})
+                     for i in range(10)],
+    )
+    assert stats.path == "delta" and stats.reordered
+    assert stats.churn_records == 50
+    assert stats.churn_records <= stats.order_visited
+    assert stats.order_visited < 2 * stats.churn_records * np.log2(n_run)
+    assert ds._run_order == sorted(ds._running)
+    assert ds._run_by_node["n005"] == sorted(
+        nm for nm, r in ds._running.items() if r["node"] == "n005")

@@ -89,6 +89,14 @@ def test_delta_cycles_carry_the_stage_spans(thread_leak_check):
         # stage of the request itself.
         (apply,) = by_name["delta.apply"]
         (root,) = by_name["server.Assign"]
+        # The apply's counts: a node bump, a bind and an arrival (4
+        # records), each name examined by a handful of O(churn) passes.
+        assert set(apply.attrs) == {"h2d_bytes", "path", "order_visited",
+                                    "churn_records"}
+        assert apply.attrs["path"] == "delta"
+        assert apply.attrs["churn_records"] >= 4
+        assert (apply.attrs["churn_records"] <= apply.attrs["order_visited"]
+                <= 10 * apply.attrs["churn_records"])
         for name in APPLY:
             for s in by_name[name]:
                 assert s.parent_id == apply.span_id, name

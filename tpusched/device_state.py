@@ -47,6 +47,7 @@ import heapq
 import logging
 import time
 import traceback
+from bisect import bisect_left, insort
 from typing import Iterable, Mapping
 
 import jax
@@ -89,6 +90,10 @@ class ApplyStats:
     # rows_scattered counts pad rows and used-resums too, so it
     # overstates what the CLIENT changed).
     churn_records: int = 0
+    # Names the Python-level order/index/map upkeep examined: one per
+    # churned name per pass plus one per bisect lookup (a rebuild counts
+    # every record it re-sorts). O(churn) on the delta path.
+    order_visited: int = 0
 
 
 @dataclasses.dataclass
@@ -129,6 +134,51 @@ def _pad_pow2(idx: list[int]) -> np.ndarray:
     out = np.full(cap, idx[0], np.int32)
     out[:n] = idx
     return out
+
+
+def _pdb_key(rec: Mapping) -> "tuple | None":
+    """The (namespace, name) PDB a running record belongs to, or None."""
+    g = rec.get("pdb_group")
+    if not g:
+        return None
+    return (str(rec.get("namespace", "default")) or "default", g)
+
+
+def _leave(members: dict, key, name: str) -> None:
+    """Drop `name` from members[key]; a slot left empty goes, as a
+    from-scratch recompute never creates one."""
+    m = members.get(key)
+    if m is not None:
+        m.pop(name, None)
+        if not m:
+            del members[key]
+
+
+def _order_churn(old: list[str], names: set[str], live: Mapping,
+                 ) -> "tuple[list[int], list[str]]":
+    """Rows of the sorted `old` order that the churned `names` vacate
+    (ascending) and the names they insert (sorted), given the record
+    dict after the apply: one bisect per churned name."""
+    removed, inserted = [], []
+    for nm in names:
+        i = bisect_left(old, nm)
+        was = i < len(old) and old[i] == nm
+        if nm in live:
+            if not was:
+                inserted.append(nm)
+        elif was:
+            removed.append(i)
+    removed.sort()
+    inserted.sort()
+    return removed, inserted
+
+
+def _row_of(order: list[str], name: str) -> int:
+    """Row of `name` in the sorted `order` (present by contract)."""
+    i = bisect_left(order, name)
+    if i == len(order) or order[i] != name:
+        raise KeyError(name)
+    return i
 
 
 class DeviceSnapshot:
@@ -181,6 +231,9 @@ class DeviceSnapshot:
         self._run_node_name: dict[str, str] = {}
         self._pod_group_name: dict[str, str] = {}
         self._run_pdb_key: dict[str, tuple] = {}
+        # node name -> its running pods' names, sorted (the `used`
+        # re-sum's member order).
+        self._run_by_node: dict[str, list[str]] = {}
         self._state = None          # snapshot.BuiltState
         self._meta: SnapshotMeta | None = None
         self._device: ClusterSnapshot | None = None
@@ -262,7 +315,6 @@ class DeviceSnapshot:
         self._nodes = self._keyed(nodes, "node")
         self._pods = self._keyed(pods, "pod")
         self._running = self._keyed(running, "running pod")
-        self._rebuild_members()
         return self._rebuild("full_load")
 
     @staticmethod
@@ -279,6 +331,8 @@ class DeviceSnapshot:
         return out
 
     def _rebuild_members(self) -> None:
+        """Group and PDB member maps from every record (the rebuild's;
+        an apply carries them over with _update_members)."""
         self._group_members = {}
         for name, rec in self._pods.items():
             g = rec.get("pod_group")
@@ -288,16 +342,45 @@ class DeviceSnapshot:
                 )
         self._pdb_members = {}
         for name, rec in self._running.items():
-            g = rec.get("pdb_group")
-            if g:
-                key = (str(rec.get("namespace", "default")) or "default", g)
+            key = _pdb_key(rec)
+            if key is not None:
                 self._pdb_members.setdefault(key, {})[name] = int(
                     rec.get("pdb_disruptions_allowed", 0)
                 )
 
+    def _update_members(self, pods: set[str], running: set[str]) -> int:
+        """Carry the member maps over one apply from the churned names:
+        each leaves the slot the previous state's reverse maps name and
+        joins the one its record names now. Runs before
+        _update_prev_maps overwrites those maps. Returns the names
+        examined."""
+        for name in pods:
+            old = self._pod_group_name.get(name)
+            if old is not None:
+                _leave(self._group_members, old, name)
+            rec = self._pods.get(name)
+            g = rec.get("pod_group") if rec is not None else None
+            if g:
+                self._group_members.setdefault(g, {})[name] = int(
+                    rec.get("pod_group_min_member", 0)
+                )
+        for name in running:
+            old = self._run_pdb_key.get(name)
+            if old is not None:
+                _leave(self._pdb_members, old, name)
+            rec = self._running.get(name)
+            key = _pdb_key(rec) if rec is not None else None
+            if key is not None:
+                self._pdb_members.setdefault(key, {})[name] = int(
+                    rec.get("pdb_disruptions_allowed", 0)
+                )
+        return len(pods) + len(running)
+
     def _refresh_prev_maps(self) -> None:
         """Reverse maps the NEXT apply needs to find what a churned
-        record used to reference (old node, old group, old PDB)."""
+        record used to reference (old node, old group, old PDB), and
+        running pods by node, from every record (the rebuild's; an
+        apply carries them over with _update_prev_maps)."""
         self._run_node_name = {
             name: rec["node"] for name, rec in self._running.items()
         }
@@ -309,6 +392,45 @@ class DeviceSnapshot:
         for key, members in self._pdb_members.items():
             for name in members:
                 self._run_pdb_key[name] = key
+        self._run_by_node = {}
+        for name in self._run_order:
+            self._run_by_node.setdefault(self._running[name]["node"],
+                                         []).append(name)
+
+    def _update_prev_maps(self, pods: set[str], running: set[str]) -> int:
+        """_refresh_prev_maps for the churned names only: a running pod
+        that left its node or moved leaves the old node's list, and is
+        inserted in name order into the new one's. Returns the names
+        examined."""
+        for name in running:
+            old = self._run_node_name.get(name)
+            rec = self._running.get(name)
+            new = rec["node"] if rec is not None else None
+            if old != new:
+                if old is not None:
+                    members = self._run_by_node[old]
+                    del members[_row_of(members, name)]
+                    if not members:
+                        del self._run_by_node[old]
+                if new is not None:
+                    insort(self._run_by_node.setdefault(new, []), name)
+            if new is None:
+                self._run_node_name.pop(name, None)
+            else:
+                self._run_node_name[name] = new
+            key = _pdb_key(rec) if rec is not None else None
+            if key is None:
+                self._run_pdb_key.pop(name, None)
+            else:
+                self._run_pdb_key[name] = key
+        for name in pods:
+            rec = self._pods.get(name)
+            g = rec.get("pod_group") if rec is not None else None
+            if g:
+                self._pod_group_name[name] = g
+            else:
+                self._pod_group_name.pop(name, None)
+        return len(pods) + len(running)
 
     def _put(self, snap_np: ClusterSnapshot) -> ClusterSnapshot:
         """Upload a full snapshot — sharded in the canonical mesh layout
@@ -362,6 +484,7 @@ class DeviceSnapshot:
         # burned every row, so start empty.
         self._pod_pc = {}
         self._run_anti = {}
+        self._rebuild_members()
         self._refresh_prev_maps()
         self._device = self._put(snap_np)
         # A rebuild replaces every device array: any carried warm
@@ -383,7 +506,9 @@ class DeviceSnapshot:
             "device.rebuild", dur_s=time.perf_counter() - t0, cat="device",
             reason=reason, h2d_bytes=nbytes,
         )
-        return ApplyStats(path="rebuild", reason=reason, h2d_bytes=nbytes)
+        return ApplyStats(path="rebuild", reason=reason, h2d_bytes=nbytes,
+                          order_visited=len(self._node_order)
+                          + len(self._pod_order) + len(self._run_order))
 
     # -- incremental apply --------------------------------------------------
 
@@ -423,8 +548,8 @@ class DeviceSnapshot:
         # is gone cannot be encoded (the fresh decoder raises the same
         # way), and raising mid-apply must not leave records and device
         # arrays disagreeing.
-        nodes_after = (set(self._nodes) | {r["name"] for r in upsert_nodes}
-                       ) - set(remove_nodes)
+        up_n = {r["name"] for r in upsert_nodes}
+        rm_n = set(remove_nodes)
         removed_r = set(remove_running)
         upserted_r = {u["name"] for u in upsert_running}
         check = list(upsert_running)
@@ -432,7 +557,8 @@ class DeviceSnapshot:
             check += [rec for name, rec in self._running.items()
                       if name not in removed_r and name not in upserted_r]
         for rec in check:
-            if rec["node"] not in nodes_after:
+            n = rec["node"]
+            if (n not in self._nodes and n not in up_n) or n in rm_n:
                 raise ValueError(
                     f"running pod {rec.get('name')!r} references missing "
                     f"node {rec['node']!r}"
@@ -453,15 +579,20 @@ class DeviceSnapshot:
             self._running[rec["name"]] = rec
         for name in remove_running:
             self._running.pop(name, None)
-        self._rebuild_members()
         churn = (len(upsert_nodes) + len(remove_nodes) + len(upsert_pods)
                  + len(remove_pods) + len(upsert_running)
                  + len(remove_running))
+        # Every name this apply upserts or removes, per collection.
+        churned = (up_n | rm_n,
+                   {r["name"] for r in upsert_pods} | set(remove_pods),
+                   upserted_r | removed_r)
         try:
+            visited = self._update_members(churned[1], churned[2])
             stats = self._apply_incremental(
                 upsert_nodes, remove_nodes, upsert_pods, remove_pods,
-                upsert_running, remove_running, laps,
+                upsert_running, remove_running, churned, laps,
             )
+            stats.order_visited += visited
         except _NeedsRebuild as e:
             laps.close()
             stats = self._rebuild(e.reason)
@@ -477,7 +608,7 @@ class DeviceSnapshot:
 
     def _apply_incremental(self, upsert_nodes, remove_nodes, upsert_pods,
                            remove_pods, upsert_running, remove_running,
-                           laps) -> ApplyStats:
+                           churned, laps) -> ApplyStats:
         st = self._state
         intr = st.interner
         bk = st.buckets
@@ -595,7 +726,7 @@ class DeviceSnapshot:
                 if len(st.group_idx) >= bk.pod_groups:
                     raise _NeedsRebuild("group_bucket")
                 st.group_idx[g] = len(st.group_idx)
-        touched_groups &= set(st.group_idx)
+        touched_groups = {g for g in touched_groups if g in st.group_idx}
         touched_pdbs = set()
         for rec in norm_running.values():
             if rec["pdb_group"] is not None:
@@ -613,7 +744,7 @@ class DeviceSnapshot:
                 if len(st.pdb_idx) >= bk.pdb_groups:
                     raise _NeedsRebuild("pdb_bucket")
                 st.pdb_idx[key] = len(st.pdb_idx)
-        touched_pdbs &= set(st.pdb_idx)
+        touched_pdbs = {k for k in touched_pdbs if k in st.pdb_idx}
 
         # Commit normalized forms + compiled caches.
         for name in remove_nodes:
@@ -644,65 +775,67 @@ class DeviceSnapshot:
             old_node = self._run_node_name.get(name)
             if old_node is not None:
                 node_churn.add(old_node)
-        node_churn &= set(self._nodes)
-        # Name-order upkeep: every pass below walks the whole cluster.
+        node_churn = {nm for nm in node_churn if nm in self._nodes}
+        # Name-order upkeep from the churned names alone: bisects into
+        # the sorted orders, C-level list and array copies, no pass in
+        # Python over the cluster.
         laps.lap("apply.order")
-        self._refresh_prev_maps()
-
-        new_node_order = sorted(self._nodes)
-        new_pod_order = sorted(self._pods)
-        new_run_order = sorted(self._running)
+        node_names, pod_names, run_names = churned
+        visited = self._update_prev_maps(pod_names, run_names)
+        node_rm, node_ins = _order_churn(self._node_order, node_names,
+                                         self._nodes)
+        pod_rm, pod_ins = _order_churn(self._pod_order, pod_names, self._pods)
+        run_rm, run_ins = _order_churn(self._run_order, run_names,
+                                       self._running)
+        visited += (len(node_names) + len(pod_names) + len(run_names)
+                    + len(node_ins) + len(pod_ins) + len(run_ins))
 
         # Permutations for insertion/removal (None = steady-state
         # value churn, pure scatter).
-        node_perm, node_pads = self._perm(self._node_order, new_node_order,
-                                          bk.nodes)
-        pod_perm, pod_pads = self._perm(self._pod_order, new_pod_order,
-                                        bk.pods)
-        run_perm, run_pads = self._perm(self._run_order, new_run_order,
-                                        bk.running_pods)
+        new_node_order, node_perm, node_pads, node_runs = self._reorder(
+            self._node_order, node_rm, node_ins, bk.nodes)
+        new_pod_order, pod_perm, pod_pads, _ = self._reorder(
+            self._pod_order, pod_rm, pod_ins, bk.pods)
+        new_run_order, run_perm, run_pads, _ = self._reorder(
+            self._run_order, run_rm, run_ins, bk.running_pods)
         node_reorder = node_perm is not None
         if node_reorder:
             # Node rows moved: every running row's node_idx needs a
             # remap (one [M] int32 column, not a per-row re-encode).
-            old_pos = {nm: i for i, nm in enumerate(self._node_order)}
             remap = np.full(bk.nodes, -1, np.int32)
-            for new_i, nm in enumerate(new_node_order):
-                if nm in old_pos:
-                    remap[old_pos[nm]] = new_i
+            for o, n, length in node_runs:
+                remap[o:o + length] = np.arange(n, n + length)
 
-        # Reorder the numpy mirror first (fancy-index gather allocates
-        # fresh arrays), then re-encode churned rows at NEW positions,
+        # Reorder the numpy mirror first (the gather allocates fresh
+        # arrays), then re-encode churned rows at NEW positions,
         # then pad vacated tail rows.
         for holder, perm in ((st.nodes_np, node_perm),
                              (st.pods_np, pod_perm),
                              (st.run_np, run_perm)):
             if perm is None:
                 continue
+            rows = perm.astype(np.intp)
             for f, arr in list(vars(holder).items()):
-                setattr(holder, f, np.ascontiguousarray(arr[perm]))
+                setattr(holder, f, np.take(arr, rows, axis=0))
         if node_reorder:
             ni = st.run_np.node_idx
             st.run_np.node_idx = np.where(
                 ni >= 0, remap[ni], ni
             ).astype(np.int32)
+            st.node_index = {nm: i for i, nm in enumerate(new_node_order)}
+            visited += len(new_node_order)
         mirror = _snapshot_from_arrays(st.nodes_np, st.pods_np, st.run_np,
                                        st.tables)
-        st.node_index = {nm: i for i, nm in enumerate(new_node_order)}
-        pod_index = {nm: i for i, nm in enumerate(new_pod_order)}
-        run_index = {nm: i for i, nm in enumerate(new_run_order)}
-
-        run_by_node: dict[str, list[str]] = {}
-        for name in new_run_order:
-            run_by_node.setdefault(self._norm_running[name]["node"],
-                                   []).append(name)
+        pod_row = {nm: _row_of(new_pod_order, nm) for nm in pod_churn}
+        run_row = {nm: _row_of(new_run_order, nm) for nm in run_churn}
+        visited += len(pod_row) + len(run_row)
         laps.lap("apply.encode")
         for nm in node_churn:
             i = st.node_index[nm]
             _fill_node_row(st.nodes_np, i, self._norm_nodes[nm], intr, cfg)
             # Re-sum counted members in name order: float-identical to a
             # rebuild, never drifting through +=/-= pairs.
-            for member in run_by_node.get(nm, ()):
+            for member in self._run_by_node.get(nm, ()):
                 rrec = self._norm_running[member]
                 if rrec["count_into_used"]:
                     for r, rn in enumerate(cfg.resources):
@@ -710,10 +843,10 @@ class DeviceSnapshot:
                             rrec["requests"].get(rn, 0.0)
                         )
         for nm in pod_churn:
-            _fill_pod_row(st.pods_np, pod_index[nm], self._norm_pods[nm],
+            _fill_pod_row(st.pods_np, pod_row[nm], self._norm_pods[nm],
                           self._pod_pc[nm], intr, cfg, st.group_idx)
         for nm in run_churn:
-            _fill_running_row(st.run_np, run_index[nm],
+            _fill_running_row(st.run_np, run_row[nm],
                               self._norm_running[nm], self._run_anti[nm],
                               intr, cfg, st.node_index, st.pdb_idx)
         for i in node_pads:
@@ -787,11 +920,11 @@ class DeviceSnapshot:
         )
         pods_dev = scatter(
             pods_dev, mirror.pods,
-            [pod_index[nm] for nm in pod_churn] + list(pod_pads),
+            [pod_row[nm] for nm in pod_churn] + list(pod_pads),
         )
         run_dev = scatter(
             run_dev, mirror.running,
-            [run_index[nm] for nm in run_churn] + list(run_pads),
+            [run_row[nm] for nm in run_churn] + list(run_pads),
         )
         atoms_dev = scatter(dev.atoms, mirror.atoms,
                             list(range(n_atoms0, len(intr.atoms))))
@@ -823,9 +956,10 @@ class DeviceSnapshot:
             self._warm_dirty_nodes |= node_churn
             self._warm_dirty_pods |= pod_churn
             self._warm_dirty_runs |= run_churn
+        # The meta shares the orders: no order list is mutated in place.
         self._meta = SnapshotMeta(
-            node_names=list(new_node_order),
-            pod_names=list(new_pod_order),
+            node_names=new_node_order,
+            pod_names=new_pod_order,
             n_nodes=len(new_node_order), n_pods=len(new_pod_order),
             n_running=len(new_run_order), buckets=bk,
             # ID order, not name order: appended mid-session groups get
@@ -833,7 +967,7 @@ class DeviceSnapshot:
             # must keep naming group id i.
             group_names=[g for g, _ in sorted(st.group_idx.items(),
                                               key=lambda kv: kv[1])],
-            running_names=list(new_run_order),
+            running_names=new_run_order,
         )
         laps.close()
         self.delta_updates += 1
@@ -843,6 +977,7 @@ class DeviceSnapshot:
             path="delta", h2d_bytes=h2d, rows_scattered=rows_written,
             reordered=(node_perm is not None or pod_perm is not None
                        or run_perm is not None),
+            order_visited=visited,
         )
 
     # -- warm-start residency (ROADMAP item 3) ------------------------------
@@ -1011,6 +1146,43 @@ class DeviceSnapshot:
                 carry[i] = ni
                 chos[i] = np.float32(c[j])
         return carry, chos
+
+    @staticmethod
+    def _reorder(old: list[str], removed: list[int], inserted: list[str],
+                 bucket: int):
+        """The new name order after vacating the `removed` rows
+        (ascending) and inserting the sorted `inserted` names (none in
+        `old`): (order, perm, pads, runs) where perm and pads are what
+        `_perm(old, order, bucket)` gives and runs lists each stretch of
+        surviving rows as (old row, new row, length). Unchanged: (old,
+        None, [], []). A new list is built; `old` is never mutated (the
+        meta, the warm anchor and the carry hold earlier orders)."""
+        if not removed and not inserted:
+            return old, None, [], []
+        at = [bisect_left(old, nm) for nm in inserted]
+        # One merge: an insert lands before the row at its position, a
+        # removal skips that row, and the rows between move as a block.
+        events = sorted([(a, 0, nm) for a, nm in zip(at, inserted)]
+                        + [(r, 1, "") for r in removed])
+        order: list[str] = []
+        runs = []
+        cur = 0
+        for pos, gone, nm in events + [(len(old), 1, "")]:
+            if pos > cur:
+                runs.append((cur, len(order), pos - cur))
+                order += old[cur:pos]
+            if gone:
+                cur = pos + 1
+            else:
+                order.append(nm)
+                cur = pos
+        # Inserted rows keep their own index (the placeholder `_perm`
+        # gives a name with no old row).
+        perm = np.arange(bucket, dtype=np.int32)
+        for o, n, length in runs:
+            perm[n:n + length] -= n - o
+        pads = list(range(len(order), len(old)))
+        return order, perm, pads, runs
 
     @staticmethod
     def _perm(old_order: list[str], new_order: list[str], bucket: int):

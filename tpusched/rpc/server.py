@@ -1547,8 +1547,10 @@ class SchedulerService:
                                               cat="server") as sp:
                             stats = session.apply_delta(  # tpl: disable=TPL102(the apply IS the critical section: a concurrent apply moving the lineage past this request's base must fork, not interleave, and the H2D scatter is the apply itself)
                                 base_id, request.delta, sid)
-                            sp.attrs.update(h2d_bytes=stats.h2d_bytes,
-                                            path=stats.path)
+                            sp.attrs.update(
+                                h2d_bytes=stats.h2d_bytes, path=stats.path,
+                                order_visited=stats.order_visited,
+                                churn_records=stats.churn_records)
                         apply_s = time.perf_counter() - t_a
                         snap, meta = session.device.snap, session.device.meta
                 except KeyError:
