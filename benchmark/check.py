@@ -1,9 +1,11 @@
 """What decides `correct`: the window's replies against the reference.
 
-Every number compared has its limit in the configuration file
-(`checks`), set from readings of sound runs and of the control
-(PERF.md gives both). The numbers, by the guarantee the configuration
-states:
+The reference is the configuration's own module (harness.plugins),
+called through its protocol (reference.py's docstring) on the
+generator's views. Every number compared has its limit in the
+configuration file (`checks`), set from readings of sound runs and of
+the control (PERF.md gives both). The numbers, by the guarantee the
+configuration states:
 
   * every configuration
       failed_cycles      Assign calls that raised (limit 0);
@@ -18,8 +20,10 @@ states:
                          placed pods committed out of the reference's
                          pop order (limit 0: an exact comparison).
   * guarantee "valid" (fast), over every cycle of the window:
-      overfull_nodes     nodes whose requests exceed allocatable once
-                         the reply's placements are added (limit 0);
+      each of the reference's VALIDITY_AUDITS, summed over the cycles
+      (the k8s5k reference's: overfull_nodes, nodes whose requests
+      exceed allocatable once the reply's placements are added, limit
+      0);
       placed_net_gap     |sum placed - sum reference placed| / sum
                          reference placed, the reference run on each
                          cycle's own input: the net placed-pod delta
@@ -29,8 +33,6 @@ states:
 from __future__ import annotations
 
 import numpy as np
-
-import reference
 
 UNCHECKED = 10**9
 
@@ -48,8 +50,10 @@ def sample_cycles(cycles: list, seed: int, k: int) -> list[int]:
     return sorted([top] + [rest[int(j)] for j in pick])
 
 
-def judge(spec: dict, alloc: np.ndarray, cycles: list, seed: int) -> dict:
-    """{name: (value, limit)} for the run's window."""
+def judge(spec: dict, reference, static, cycles: list, seed: int) -> dict:
+    """{name: (value, limit)} for the run's window, judged by the
+    `reference` module on the generator's `static` view and each
+    cycle's record."""
     cfg = spec["config"]
     limits = cfg["checks"]
     profile = cfg["engine"]
@@ -69,21 +73,18 @@ def judge(spec: dict, alloc: np.ndarray, cycles: list, seed: int) -> dict:
         differing = 0
         for i in sample:
             r = recs[i]
-            ref, pos = reference.sequential(profile, alloc, r["used"],
-                                            r["pend"])
+            ref, pos = reference.sequential(profile, static, r)
             differing += int((r["node"] != ref).sum())
             differing += reference.commit_rank_differs(
                 np.where(ref == r["node"], r["node"], -1), r["key"], pos)
         out["pods_differing"] = (differing + none, limits["pods_differing"])
     elif cfg["guarantee"] == "valid":
-        out["overfull_nodes"] = (
-            sum(reference.overfull_nodes(alloc, r["used"], r["pend"],
-                                         r["node"]) for r in good),
-            limits["overfull_nodes"])
+        for name in reference.VALIDITY_AUDITS:
+            audit = getattr(reference, name)
+            out[name] = (sum(audit(static, r) for r in good), limits[name])
         placed = want = 0
         for r in good:
-            ref, _ = reference.sequential(profile, alloc, r["used"],
-                                          r["pend"])
+            ref, _ = reference.sequential(profile, static, r)
             want += int((ref >= 0).sum())
             placed += int((r["node"] >= 0).sum())
         gap = abs(placed - want) / max(want, 1) if good else UNCHECKED

@@ -10,8 +10,8 @@ broken, which the comparison has to call not correct.
   * guarantee "valid" (fast): the reference put in the program's place
     with the capacity guarantee broken — every pod takes its best node
     against the cycle's starting state, nothing committed between pods
-    (`reference.sequential(commit=False)`), read on every cycle's own
-    input (no_commit_answers).
+    (the configuration's reference, `sequential(commit=False)`), read on
+    every cycle's own input (no_commit_answers).
 
 With `--fault <name>` it plants a fault in the timed path instead:
   * half_batch: the reply places only the first half of the queue (the
@@ -22,7 +22,9 @@ With `--fault <name>` it plants a fault in the timed path instead:
     pending pods but not its running-pod changes, so node usage stays
     as the session seed left it;
   * altered_answer: the reply's first placement moves to the node with
-    the least room for that pod.
+    the least cpu room for that pod (read from the views every
+    resource-fit generator gives: static `alloc`, the cycle's `used`
+    and `pend["cpu"]`).
 tests/benchmark plants each at a CPU size.
 
 The benchmark's own runs never run this. Prints the checks and one
@@ -42,7 +44,6 @@ import sys  # noqa: E402
 import numpy as np  # noqa: E402
 
 import harness  # noqa: E402
-import reference  # noqa: E402
 
 
 def no_commit_answers(drv):
@@ -59,8 +60,8 @@ def no_commit_answers(drv):
         cyc = cycle(changed, keep)
         if keep:
             r = cyc.record
-            r["node"], _ = reference.sequential(profile, drv.alloc, r["used"],
-                                                r["pend"], commit=False)
+            r["node"], _ = drv.reference.sequential(profile, drv.static, r,
+                                                    commit=False)
         return cyc
 
     drv.cycle = hook
@@ -108,8 +109,11 @@ def altered_answer(drv):
             c = drv.cluster
             j = int(placed[0])
             slot = c.pend_slot[resp.pod_names[j]]
-            room = drv.alloc[:, 0] - c.used[:, 0] - c.pend_cpu[slot]
-            worst = c.node_names[int(np.argmin(room))]
+            rec = c.cycle_view()
+            room = (drv.static["alloc"][:, 0] - rec["used"][:, 0]
+                    - rec["pend"]["cpu"][slot])
+            n = int(np.argmin(room))
+            worst = next(nm for nm, i in c.node_index.items() if i == n)
             node[j] = list(resp.node_names).index(worst)
         resp.node_idx_packed = node.astype("<i4").tobytes()
         return resp

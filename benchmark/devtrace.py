@@ -207,21 +207,28 @@ CONTAINERS = ("bench.assign", "server.Assign", "client.send")
 UNSPANNED = "client (no span: DeltaSession diff, reply read)"
 
 
+def _length(intervals: list[tuple[float, float]]) -> float:
+    return sum(e - s for s, e in _union(intervals))
+
+
 def _label(g0: float, g1: float, host) -> str:
     """What the host did in the gap [g0, g1): the stage span covering
     most of it; time inside `bench.assign` that no stage span covers
-    is the client's own unspanned work."""
+    is the client's own unspanned work. Stage spans nest (`delta.apply`
+    holds the `apply.*` stages), so that time is taken against the
+    union of the stage spans, each moment counted once."""
     cover: dict[str, float] = {}
-    inside = 0.0
+    inside, staged = [], []
     for s, e, name in host:
-        ov = min(e, g1) - max(s, g0)
-        if ov <= 0:
+        lo, hi = max(s, g0), min(e, g1)
+        if hi <= lo:
             continue
         if name == "bench.assign":
-            inside = max(inside, ov)
+            inside.append((lo, hi))
         elif name not in CONTAINERS:
-            cover[name] = cover.get(name, 0.0) + ov
-    rest = inside - sum(cover.values())
+            cover[name] = cover.get(name, 0.0) + hi - lo
+            staged.append((lo, hi))
+    rest = _length(inside + staged) - _length(staged)
     if rest > 0:
         cover[UNSPANNED] = cover.get(UNSPANNED, 0.0) + rest
     if not cover:

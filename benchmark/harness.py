@@ -4,9 +4,11 @@ The system under test is the served path a host shim uses: an
 in-process sidecar (`tpusched.rpc.server.make_server`, floor buckets
 pinned to the cell's own) driven by `SchedulerClient` + `DeltaSession`
 with `packed_ok=True`. The benchmark hands it the wire snapshot its
-generator keeps (workload.Cluster), and keeps for itself a copy of
-every cycle's input and reply, which the reference judges once the
-window has closed (check.py).
+generator keeps, and keeps for itself a copy of every cycle's input
+and reply, which the reference judges once the window has closed
+(check.py). The configuration names its generator and its reference
+by path; `plugins` loads both and holds them to their protocols
+(workload.py's and reference.py's docstrings).
 
 Traffic is one general loop over the mix's parameters:
   * arrival "closed": before each cycle the queue is topped up to
@@ -24,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib.util
 import json
 import logging
 import math
@@ -36,7 +39,6 @@ import numpy as np
 
 import check
 import devtrace
-from workload import Cluster
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = pathlib.Path(__file__).resolve().parent
@@ -62,6 +64,59 @@ def prepare_env() -> None:
 
 class NoAccelerator(RuntimeError):
     pass
+
+
+class PluginError(ValueError):
+    """A configuration's generator or reference is missing or does not
+    keep its protocol."""
+
+
+CLUSTER_METHODS = ("add_pending", "bind", "complete", "qos_churn",
+                   "static_view", "cycle_view")
+CLUSTER_ATTRS = ("msg", "node_index", "pend_names", "pend_slot", "n_pend",
+                 "pend_cap", "n_run")
+REFERENCE_CALLS = ("sequential", "commit_rank_differs")
+
+
+def load_module(kind: str, rel: str):
+    """The module at `rel` (a path from the repo root), loaded as
+    devtrace.read_metric loads metric readers."""
+    path = (ROOT / rel).resolve()
+    if not (path.is_relative_to(ROOT) and path.is_file()):
+        raise PluginError(f"the configuration's {kind} {rel!r} is not a "
+                          f"file in the repo ({ROOT})")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{path.stem}".replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def plugins(spec: dict):
+    """(generator, reference) modules the cell's configuration names,
+    each checked for what its protocol needs before anything runs."""
+    cfg = spec["config"]
+    missing = [k for k in ("generator", "reference") if k not in cfg]
+    if missing:
+        raise PluginError(f"configuration {cfg.get('name')!r} names no "
+                          f"{' and no '.join(missing)}")
+    gen = load_module("generator", cfg["generator"])
+    ref = load_module("reference", cfg["reference"])
+    cls = getattr(gen, "Cluster", None)
+    lacks = ["Cluster"] if cls is None else [
+        m for m in CLUSTER_METHODS if not callable(getattr(cls, m, None))]
+    if lacks:
+        raise PluginError(f"generator {cfg['generator']!r} lacks "
+                          f"{', '.join(lacks)}")
+    calls = list(REFERENCE_CALLS)
+    if cfg["guarantee"] == "valid":
+        audits = getattr(ref, "VALIDITY_AUDITS", None)
+        calls += ["VALIDITY_AUDITS"] if audits is None else list(audits)
+    lacks = [c for c in calls if not callable(getattr(ref, c, None))]
+    if lacks:
+        raise PluginError(f"reference {cfg['reference']!r} lacks "
+                          f"{', '.join(lacks)}")
+    return gen, ref
 
 
 def load_json(path: pathlib.Path) -> dict:
@@ -93,15 +148,17 @@ def cell_spec(workload: str) -> dict:
 
 
 def cell_buckets(spec: dict):
-    """The sidecar's floor buckets, pinned to the cell's own sizes so
-    that nothing in the window grows a bucket (and compiles)."""
+    """The sidecar's floor buckets, pinned to the cell's own sizes (and
+    to the configuration's further `buckets`, by Buckets field) so that
+    nothing in the window grows a bucket (and compiles)."""
     from tpusched.config import Buckets
 
-    mix = spec["traffic"]
+    mix, cfg = spec["traffic"], spec["config"]
     return dataclasses.replace(
         Buckets.minimal(1, 1, 1), pods=int(mix["pending_bucket"]),
-        nodes=int(spec["config"]["node_bucket"]),
-        running_pods=int(mix["running_bucket"]))
+        nodes=int(cfg["node_bucket"]),
+        running_pods=int(mix["running_bucket"]),
+        **{k: int(v) for k, v in cfg.get("buckets", {}).items()})
 
 
 class _CompileCounter:
@@ -160,8 +217,13 @@ class Driver:
         self.spec = spec
         self.mix = spec["traffic"]
         self.cfg = EngineConfig.from_dict(spec["config"]["engine"])
-        self.cluster = Cluster(spec["config"], self.mix, seed)
-        self.alloc = self.cluster.alloc()
+        gen, self.reference = plugins(spec)
+        self.cluster = gen.Cluster(spec["config"], self.mix, seed)
+        lacks = [a for a in CLUSTER_ATTRS if not hasattr(self.cluster, a)]
+        if lacks:
+            raise PluginError(f"generator {spec['config']['generator']!r}: "
+                              f"its Cluster lacks {', '.join(lacks)}")
+        self.static = self.cluster.static_view()
         self.server, port, self.svc = make_server(
             config=self.cfg, buckets=cell_buckets(spec), log_stream=_Null(),
             tracer=tracer)
@@ -235,10 +297,7 @@ class Driver:
         return node, keys, malformed
 
     def cycle(self, changed, keep: bool) -> Cycle:
-        c = self.cluster
-        rec = None
-        if keep:
-            rec = dict(used=c.used.copy(), pend=c.pending_view())
+        rec = self.cluster.cycle_view() if keep else None
         t0 = time.perf_counter()
         node, keys, malformed = self.assign(changed)
         t1 = time.perf_counter()
@@ -273,8 +332,9 @@ class Arrivals:
         return self.t0 + float(self.t[i])
 
 
-def warm_scatters(svc, min_rows: int, max_rows: int) -> int:
-    """Compile the delta path's row scatters for every pow2 size from
+def warm_scatters(svc, trees, min_rows: int, max_rows: int) -> int:
+    """Compile the delta path's row scatters of the snapshot's row
+    `trees` (the mix's `warm_trees`) for every pow2 size from
     `min_rows` to `max_rows` (the churn sizes the mix can reach in its
     window), on the session's own arrays (identical rows written back),
     so none compiles in the window. Returns how many programs were
@@ -288,7 +348,7 @@ def warm_scatters(svc, min_rows: int, max_rows: int) -> int:
         raise RuntimeError("want exactly one device session")
     snap = session.device.snap
     n = 0
-    for tree in (snap.nodes, snap.pods, snap.running):
+    for tree in (getattr(snap, name) for name in trees):
         host = jax.tree.map(np.asarray, tree)
         size = min_rows
         while size <= max_rows:
@@ -403,7 +463,7 @@ def setup(drv: Driver, seed: int, seconds: float):
               set(c.qos_churn(float(mix["qos_churn"]))), keep=False)
     stages["session_seed"] = time.perf_counter() - t
     t = time.perf_counter()
-    warm_scatters(drv.svc, *mix["warm_rows"])
+    warm_scatters(drv.svc, mix["warm_trees"], *mix["warm_rows"])
     stages["scatter_warm"] = time.perf_counter() - t
     arrivals = None
     if not closed:
@@ -587,7 +647,7 @@ def _run(drv: Driver, spec, seed, seconds, trace, device, t_start,
     # sidecar's state is freed.
     drv.close()
     t = time.perf_counter()
-    checks = check.judge(spec, drv.alloc, cycles, seed)
+    checks = check.judge(spec, drv.reference, drv.static, cycles, seed)
     say(phase="check", s=time.perf_counter() - t)
     correct = all(v <= lim for v, lim in checks.values())
     if trace:

@@ -1,8 +1,20 @@
-"""The plain reference: what a scheduling cycle must return.
+"""The plain reference of the k8s5k configurations: what a scheduling
+cycle must return.
 
 Imports nothing of the program and reads nothing it made: its inputs
-are the benchmark's own copy of each cycle (workload.Cluster's arrays
-as they were sent), and the configuration file's scheduler profile.
+are the generator's views (workload.Cluster's `static_view()` and the
+`cycle_view()` of each cycle as it was sent), and the configuration
+file's scheduler profile. A configuration names its reference by path
+(`"reference"`); the check calls this protocol, which every reference
+keeps:
+
+  * `sequential(profile, static, rec, commit=True) -> (node, pos)`;
+  * `commit_rank_differs(node, key, pos)`;
+  * `VALIDITY_AUDITS`: the names of the audits a configuration of
+    guarantee "valid" is held to, each a function `(static, rec) ->
+    int` of this module counting violations in one cycle's reply
+    (`rec["node"]`), with its limit under the same name in the
+    configuration's `checks`.
 
 `sequential` is kube-scheduler's `scheduleOne` loop for the profile
 the configurations state (NodeResourcesFit filter, LeastAllocated +
@@ -23,6 +35,7 @@ import numpy as np
 
 F32 = np.float32
 MIB = 1 << 20
+VALIDITY_AUDITS = ("overfull_nodes",)
 _PLUGINS = ("least_requested", "balanced_allocation", "node_affinity",
             "taint_toleration", "topology_spread", "interpod_affinity")
 
@@ -32,16 +45,15 @@ def _requests(pend: dict) -> tuple[np.ndarray, np.ndarray]:
             (pend["mem"] * MIB).astype(F32))
 
 
-def sequential(profile: dict, alloc: np.ndarray, used: np.ndarray,
-               pend: dict, commit: bool = True
+def sequential(profile: dict, static: dict, rec: dict, commit: bool = True
                ) -> tuple[np.ndarray, np.ndarray]:
     """(node [P] int64, -1 = unplaced; pop position [P] int64) for the
-    pending pods `pend` (by slot), on nodes in name order.
+    pending pods `rec["pend"]` (by slot), on nodes in name order.
 
-    alloc/used: [N, 3] int64 (cpu millicores, memory MiB, pods).
-    commit=False is the control of the fast configurations (control.py):
-    every pod takes its best node against the cycle's starting state,
-    the capacity guarantee broken."""
+    static["alloc"], rec["used"]: [N, 3] int64 (cpu millicores, memory
+    MiB, pods). commit=False is the control of the fast configurations
+    (control.py): every pod takes its best node against the cycle's
+    starting state, the capacity guarantee broken."""
     w_plugin = {p: float(profile["weights"][p]) for p in _PLUGINS}
     qos = profile["qos"]
     gain = float(qos["qos_gain"])
@@ -50,6 +62,7 @@ def sequential(profile: dict, alloc: np.ndarray, used: np.ndarray,
             float(res_w.get("pods", 0.0))) != (1.0, 1.0, 0.0):
         raise ValueError("the reference is written for cpu:1 memory:1 "
                          "pods:0 resource weights")
+    alloc, used, pend = static["alloc"], rec["used"], rec["pend"]
     a_c, a_m, a_p = (alloc[:, 0].astype(F32), (alloc[:, 1] * MIB).astype(F32),
                      alloc[:, 2].astype(F32))
     u_c, u_m, u_p = (used[:, 0].astype(F32), (used[:, 1] * MIB).astype(F32),
@@ -110,16 +123,16 @@ def sequential(profile: dict, alloc: np.ndarray, used: np.ndarray,
     return node, pos
 
 
-def overfull_nodes(alloc: np.ndarray, used: np.ndarray, pend: dict,
-                   node: np.ndarray) -> int:
+def overfull_nodes(static: dict, rec: dict) -> int:
     """Nodes whose requests exceed allocatable once the placed pods
-    `node` (by slot, -1 = unplaced) are added (exact integers)."""
+    `rec["node"]` (by slot, -1 = unplaced) are added (exact integers)."""
+    pend, node = rec["pend"], rec["node"]
     placed = node >= 0
-    after = used.copy()
+    after = rec["used"].copy()
     np.add.at(after, node[placed],
               np.c_[pend["cpu"][placed], pend["mem"][placed],
                     np.ones(int(placed.sum()), np.int64)])
-    return int((after > alloc).any(axis=1).sum())
+    return int((after > static["alloc"]).any(axis=1).sum())
 
 
 def commit_rank_differs(node: np.ndarray, key: np.ndarray,

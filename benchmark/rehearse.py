@@ -8,7 +8,7 @@ drives everything around it: the cell's generator at its real size
 (5,000 nodes, ~140k running pods, the mix's queue), the client diff
 (`codec.delta_between`, as `DeltaSession` runs it), and the sidecar's
 device-resident session (`DeviceSession.from_base_store` +
-`apply_delta`, on CPU arrays). Placements come from the benchmark's
+`apply_delta`, on CPU arrays). Placements come from the configuration's
 reference, so the bind / complete / arrive waves are the ones the
 cell will make. It fails unless every cycle stays on the delta rung
 with 0 device-state rebuilds and the session's arrays equal a fresh
@@ -42,14 +42,12 @@ def main(argv=None) -> int:
     from tpusched.rpc import codec
     from tpusched.rpc.server import DeviceSession
 
-    import reference
-    from workload import Cluster
-
     spec = harness.cell_spec(args.workload)
     mix, conf = spec["traffic"], spec["config"]
+    gen, reference = harness.plugins(spec)
     cfg = EngineConfig.from_dict(conf["engine"])
-    c = Cluster(conf, mix, args.seed)
-    alloc = c.alloc()
+    c = gen.Cluster(conf, mix, args.seed)
+    static = c.static_view()
     bk = harness.cell_buckets(spec)
     if mix["arrival"] == "closed":
         c.add_pending(int(mix["backlog"]))
@@ -65,8 +63,7 @@ def main(argv=None) -> int:
     sid = "s0"
     bad = []
     for k in range(args.cycles):
-        node, _ = reference.sequential(conf["engine"], alloc, c.used,
-                                       c.pending_view())
+        node, _ = reference.sequential(conf["engine"], static, c.cycle_view())
         placed = np.flatnonzero(node >= 0)
         names = [c.pend_names[s] for s in placed]
         bound = c.bind(names, [int(node[s]) for s in placed])
@@ -100,9 +97,10 @@ def main(argv=None) -> int:
         jax.tree.leaves((fresh.nodes.used, fresh.nodes.allocatable,
                          fresh.pods.requests, fresh.running.requests,
                          fresh.running.node_idx))))
+    used = c.cycle_view()["used"]
     used_ok = np.array_equal(
-        np.asarray(fresh.nodes.used)[:c.n_nodes],
-        (c.used * np.array([1, 1 << 20, 1])).astype(np.float32))
+        np.asarray(fresh.nodes.used)[:len(used)],
+        (used * np.array([1, 1 << 20, 1])).astype(np.float32))
     print(json.dumps(dict(phase="done", delta_rung=not bad,
                           session_equals_fresh_decode=bool(same),
                           used_equals_generator=bool(used_ok))), flush=True)

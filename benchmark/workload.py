@@ -1,10 +1,30 @@
-"""The benchmark's own cluster and traffic generator.
+"""The cluster generator of the k8s5k configurations.
 
-One general generator, driven by two data files: a configuration
-(`configs/<name>.json`: the cluster's sizes and the pod size
-distribution) and a traffic mix (`traffic/<name>.json`: how pods
-arrive, how many complete, how much QoS churn). Everything is drawn
-from the run's seed, in bulk with numpy.
+A configuration names its generator by path (`"generator"` in
+`configs/<name>.json`); the harness loads it and drives it through
+this protocol, which every generator keeps:
+
+  * `Cluster(config, traffic, seed)`: the configuration file and the
+    traffic mix as dicts, and the run's seed;
+  * attributes `msg` (the `ClusterSnapshot` the client sends, mutated
+    in place), `node_index` (node name -> index), `pend_names` (pending
+    pod names by slot), `pend_slot` (name -> slot), `n_pend`,
+    `pend_cap` (the queue's bucket) and `n_run`;
+  * `add_pending(k)`, `bind(names, nodes)`, `complete(k)` and
+    `qos_churn(frac)`, each returning the names it touched, called in
+    that order between two cycles;
+  * `static_view()`, read once per run, and `cycle_view()`, read for
+    every cycle the check keeps: what the configuration's reference
+    reads, passed through the harness and the check whole. The cycle
+    view is a dict whose `pend["names"]` are the pending pods' names by
+    slot (the harness times pods by them) and which uses none of the
+    keys the harness adds (`node`, `key`, `malformed`, `failed`).
+
+This one is driven by two data files: a configuration (the cluster's
+sizes and the pod size distribution) and a traffic mix
+(`traffic/<name>.json`: how pods arrive, how many complete, how much
+QoS churn). Everything is drawn from the run's seed, in bulk with
+numpy.
 
 The state lives twice, kept in step by every mutation:
   * numpy arrays (the benchmark's truth, which the reference reads);
@@ -347,17 +367,19 @@ class Cluster:
 
     # -- what the reference reads -----------------------------------------
 
-    def pending_view(self) -> dict:
-        """Copy of the pending pods as sent this cycle, by slot."""
+    def static_view(self) -> dict:
+        """`alloc`: [N, 3] allocatable (cpu millicores, memory MiB,
+        pods)."""
+        pods = np.full(self.n_nodes, self.pods_cap, np.int64)
+        return dict(alloc=np.c_[self.node_cpu, self.node_mem, pods])
+
+    def cycle_view(self) -> dict:
+        """Copy of the cycle's input as sent: `used` [N, 3] like
+        `alloc`, and the pending pods by slot in `pend`."""
         n = self.n_pend
-        return dict(
+        return dict(used=self.used.copy(), pend=dict(
             names=np.array(self.pend_names[:n]),
             cpu=self.pend_cpu[:n].copy(), mem=self.pend_mem[:n].copy(),
             prio=self.pend_prio[:n].copy(), slo=self.pend_slo[:n].copy(),
             avail=self.pend_avail[:n].copy(),
-        )
-
-    def alloc(self) -> np.ndarray:
-        """[N, 3] allocatable (cpu millicores, memory MiB, pods)."""
-        return np.c_[self.node_cpu, self.node_mem,
-                     np.full(self.n_nodes, self.pods_cap, np.int64)]
+        ))

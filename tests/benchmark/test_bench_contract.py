@@ -1,7 +1,9 @@
 """BENCHMARK.json keeps to the benchmark contract's shape, every file
-it names exists, and the command refuses to run without a TPU or
-without the program beside it."""
+it names exists (and every module and row tree a configuration or a
+mix names), and the command refuses to run without a TPU or without
+the program beside it."""
 
+import dataclasses
 import json
 import os
 import re
@@ -46,7 +48,11 @@ def test_configs_and_cells(bench):
         assert NAME.match(c["name"])
         assert c["file"].startswith("benchmark/")
         cfg = json.loads((ROOT / c["file"]).read_text())
-        assert cfg["name"] == c["name"] and c["reduced"] == []
+        assert cfg["name"] == c["name"]
+        # Every cut from the source is listed in both, by a key of the
+        # configuration's file.
+        assert c["reduced"] == cfg["reduced"]
+        assert set(c["reduced"]) <= set(cfg) and len(c["reduced"]) <= 16
         assert cfg["guarantee"] in ("sequential", "valid")
     pairs = set()
     for w in bench["workloads"]:
@@ -59,6 +65,28 @@ def test_configs_and_cells(bench):
     assert len(pairs) == len(bench["workloads"])
     used = {w["config"] for w in bench["workloads"]}
     assert used == set(names)
+
+
+def test_configs_name_their_generator_and_reference(bench):
+    for c in bench["configs"]:
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        for kind in ("generator", "reference"):
+            path = cfg[kind]
+            assert path.startswith("benchmark/") and path.endswith(".py")
+            assert (ROOT / path).is_file(), (c["name"], kind, path)
+
+
+def test_warm_trees_are_row_trees(bench):
+    from tpusched.snapshot import ClusterSnapshot
+
+    # DeviceSnapshot.snap is a ClusterSnapshot; its fields are the row
+    # trees the delta apply scatters into.
+    trees = {f.name for f in dataclasses.fields(ClusterSnapshot)}
+    for traffic in {w["traffic"] for w in bench["workloads"]}:
+        mix = json.loads((ROOT / "benchmark" / "traffic" / f"{traffic}.json"
+                          ).read_text())
+        assert mix["warm_trees"], traffic
+        assert set(mix["warm_trees"]) <= trees, traffic
 
 
 def test_metrics(bench):

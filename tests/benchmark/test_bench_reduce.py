@@ -56,6 +56,23 @@ def test_synthetic_trace():
     assert ops[0] == ["fusion.1", pytest.approx(0.020)]
 
 
+def test_nested_stage_spans_count_once():
+    # One idle gap, 10..110 ms, inside one bench.assign: delta.apply
+    # covers 40 ms of it and its two apply.* stages the same 40 ms, so
+    # 60 ms is the client's own unspanned work.
+    dev = dict(name="/device:TPU:0", lines=[dict(name="XLA Ops", events=[
+        ("fusion.1", 0.0, 10 * MS), ("fusion.2", 110 * MS, 10 * MS)])])
+    host = dict(name="/host:CPU", lines=[dict(name="python", events=[
+        (devtrace.CLOCK, 0.0, 0.01 * MS), ("bench.assign", 0.0, 120 * MS)])])
+    spans = [dict(t_wall=1000.0 + a, dur_s=d, name=n) for n, a, d in (
+        ("server.Assign", 0.005, 0.110), ("delta.apply", 0.010, 0.040),
+        ("apply.order", 0.010, 0.020), ("apply.encode", 0.030, 0.020))]
+    red = devtrace.reduce_profile([dev, host], 0.120, 1000.0, spans)
+    [(label, seconds)] = red["breakdown"]["idle_gaps"]
+    assert label == devtrace.UNSPANNED
+    assert seconds == pytest.approx(0.100)
+
+
 def test_no_device_plane_is_an_error():
     with pytest.raises(RuntimeError):
         devtrace.reduce_profile(_synthetic()[1:], 0.1, 0.0)

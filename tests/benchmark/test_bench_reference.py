@@ -29,8 +29,9 @@ def test_sequential_equals_oracle_and_parity_engine(seed):
     snap, meta = decode_snapshot(c.msg, ec)
     ora = Oracle(snap, ec).solve()
     res = Engine(ec).solve(snap)
-    pend = c.pending_view()
-    ref, pos = reference.sequential(cfg["engine"], c.alloc(), c.used, pend)
+    rec = c.cycle_view()
+    pend = rec["pend"]
+    ref, pos = reference.sequential(cfg["engine"], c.static_view(), rec)
     slot = {n: i for i, n in enumerate(pend["names"])}
     by_name = [slot[n] for n in meta.pod_names]
     node_pos = {n: i for i, n in enumerate(meta.node_names)}
@@ -47,13 +48,14 @@ def test_sequential_equals_oracle_and_parity_engine(seed):
 
 
 def test_overfull_nodes_counts_exceeded_capacity():
-    alloc = np.array([[1000, 1024, 110], [2000, 2048, 110]])
-    used = np.array([[900, 0, 1], [0, 0, 0]])
-    pend = dict(cpu=np.array([100, 101, 50]), mem=np.array([1, 1, 1]))
-    assert reference.overfull_nodes(alloc, used, pend,
-                                    np.array([0, -1, 1])) == 0
-    assert reference.overfull_nodes(alloc, used, pend,
-                                    np.array([0, 0, 1])) == 1
+    static = dict(alloc=np.array([[1000, 1024, 110], [2000, 2048, 110]]))
+    rec = dict(used=np.array([[900, 0, 1], [0, 0, 0]]),
+               pend=dict(cpu=np.array([100, 101, 50]),
+                         mem=np.array([1, 1, 1])))
+    assert reference.overfull_nodes(
+        static, dict(rec, node=np.array([0, -1, 1]))) == 0
+    assert reference.overfull_nodes(
+        static, dict(rec, node=np.array([0, 0, 1]))) == 1
 
 
 def test_commit_rank_differs():
@@ -67,7 +69,6 @@ def test_commit_rank_differs():
 
 def test_no_commit_control_overfills():
     cfg, c = _cluster(4)
-    node, _ = reference.sequential(cfg["engine"], c.alloc(), c.used,
-                                   c.pending_view(), commit=False)
-    assert reference.overfull_nodes(c.alloc(), c.used, c.pending_view(),
-                                    node) > 0
+    static, rec = c.static_view(), c.cycle_view()
+    node, _ = reference.sequential(cfg["engine"], static, rec, commit=False)
+    assert reference.overfull_nodes(static, dict(rec, node=node)) > 0
