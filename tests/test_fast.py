@@ -10,7 +10,8 @@ import pytest
 from tpusched import Engine, EngineConfig
 from tpusched.oracle import Oracle, validate_assignment
 from tpusched.synth import make_cluster
-from tpusched.snapshot import SnapshotBuilder
+from tpusched.snapshot import (MatchExpression, SnapshotBuilder,
+                               TopologySpreadConstraint)
 
 
 def fast_cfg():
@@ -188,3 +189,132 @@ def test_fast_capacity_exact_past_f32_prefix(seed):
     snap, _ = b.build()
     res = Engine(cfg).solve(snap)
     check_valid(snap, res, cfg)
+
+
+
+@pytest.mark.parametrize("pending, c_short", [(6, 0), (20, 0), (16, 4)])
+def test_fast_places_every_pod_under_host_and_zone_spread(pending, c_short):
+    """scheduler_perf's spread template: DoNotSchedule (maxSkew 5) on
+    the hostname and on the zone. Zone a holds the emptiest hosts (7
+    foo=bar pods each) but 40 more foo=bar pods than zones b and c, whose
+    hosts hold 11 or 13: only b's and c's 11s take a pod. The sequential
+    scheduler places every pod, and so must the rounds. Water-filled on
+    the hostname alone, every member went to zone a, which the
+    end-of-round zone skew rejects, round after round, and the rounds
+    stopped with nothing placed. c_short: zone c holds that many fewer
+    (12s for 13s), so the fill deals c its first members alone, and the
+    members after them must go on to c's next hosts, not back to its
+    first: one pod a host, nothing reverted."""
+    cfg = fast_cfg()
+    b = SnapshotBuilder(cfg)
+    foo = (MatchExpression("foo", "In", ("bar",)),)
+    spread = [TopologySpreadConstraint(key, 5, "DoNotSchedule", foo)
+              for key in ("kubernetes.io/hostname",
+                          "topology.kubernetes.io/zone")]
+    hosts = ([("a", 7)] * 40 + [("b", 11), ("b", 13)] * 10
+             + [("c", 11), ("c", 12)] * c_short
+             + [("c", 11), ("c", 13)] * (10 - c_short))
+    for i, (zone, held) in enumerate(hosts):
+        b.add_node(f"n{i}", {"cpu": 4000, "memory": 32 << 30},
+                   labels={"kubernetes.io/hostname": f"n{i}",
+                           "topology.kubernetes.io/zone": zone})
+        for _ in range(held):
+            b.add_running_pod(f"n{i}", {"cpu": 100, "memory": 500 << 20},
+                              labels={"foo": "bar"})
+    for j in range(pending):
+        b.add_pod(f"p{j}", {"cpu": 100, "memory": 500 << 20},
+                  priority=float(j % 7), labels={"foo": "bar"},
+                  topology_spread=spread)
+    snap, _ = b.build()
+    res = Engine(cfg).solve(snap)
+    check_valid(snap, res, cfg)
+    ora = Oracle(snap, cfg).solve()
+    assert (ora.assignment[:pending] >= 0).all()
+    assert (res.assignment[:pending] >= 0).all(), res.assignment[:pending]
+    assert (res.rounds, res.reverts) == (1, 0)
+
+
+def test_fast_backstop_places_what_the_water_fill_misdeals():
+    """Three DoNotSchedule keys: the water-fill balances the coarsest
+    (zone) and fans out by the finest (hostname), blind to the rack
+    between them. The emptiest hosts (7 foo=bar pods) sit in racks a1
+    and b1, which hold 20 more than a2 and b2: every water-filled member
+    lands in a rack the end-of-round skew rejects. The progress backstop
+    must then deal the reverted pods by the start-state filter (a2's
+    and b2's 11s) instead of stopping with them pending."""
+    cfg = fast_cfg()
+    b = SnapshotBuilder(cfg)
+    foo = (MatchExpression("foo", "In", ("bar",)),)
+    spread = [TopologySpreadConstraint(key, 5, "DoNotSchedule", foo)
+              for key in ("kubernetes.io/hostname", "rack",
+                          "topology.kubernetes.io/zone")]
+    hosts = []
+    for zone in "ab":
+        hosts += [(zone, zone + "1", 7)] * 20
+        hosts += [(zone, zone + "2", 11), (zone, zone + "2", 13)] * 5
+    for i, (zone, rack, held) in enumerate(hosts):
+        b.add_node(f"n{i}", {"cpu": 4000, "memory": 32 << 30},
+                   labels={"kubernetes.io/hostname": f"n{i}", "rack": rack,
+                           "topology.kubernetes.io/zone": zone})
+        for _ in range(held):
+            b.add_running_pod(f"n{i}", {"cpu": 100, "memory": 500 << 20},
+                              labels={"foo": "bar"})
+    for j in range(6):
+        b.add_pod(f"p{j}", {"cpu": 100, "memory": 500 << 20},
+                  priority=float(j), labels={"foo": "bar"},
+                  topology_spread=spread)
+    snap, _ = b.build()
+    res = Engine(cfg).solve(snap)
+    check_valid(snap, res, cfg)
+    assert (Oracle(snap, cfg).solve().assignment[:6] >= 0).all()
+    assert (res.assignment[:6] >= 0).all(), res.assignment[:6]
+    assert res.reverts > 0
+
+
+def test_fast_leading_conservative_pod_commits_alone():
+    """The progress backstop's conservative pod commits alone in its
+    signature clusters: the round it leads, the other pods it could
+    interact with sit out, so nothing moves the counts it was filtered
+    against. Two pods spread over two empty hosts, the higher-priority
+    one optimistic, the other marked conservative in the seeded state:
+    the conservative one commits in round 0, the other in round 1."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpusched.engine import _sat_tables
+    from tpusched.kernels import assign as ka
+    from tpusched.kernels import pairwise as kpair
+
+    cfg = fast_cfg()
+    b = SnapshotBuilder(cfg)
+    app = (MatchExpression("app", "In", ("x",)),)
+    for i in range(2):
+        b.add_node(f"n{i}", {"cpu": 4000, "memory": 16 << 30},
+                   labels={"kubernetes.io/hostname": f"n{i}"})
+    for name, prio in (("hi", 10.0), ("lo", 1.0)):
+        b.add_pod(name, {"cpu": 100, "memory": 1 << 28}, priority=prio,
+                  labels={"app": "x"}, topology_spread=[
+                      TopologySpreadConstraint("kubernetes.io/hostname", 1,
+                                               "DoNotSchedule", app)])
+    snap, meta = b.build()
+    snap = jax.tree.map(jnp.asarray, snap)
+    P = int(snap.pods.valid.shape[0])
+    N = int(snap.nodes.valid.shape[0])
+    static = ka.precompute_static(cfg, snap, *_sat_tables(snap))
+    order = ka.pop_order(cfg, snap)
+    rank = jnp.zeros(P, jnp.int32).at[order].set(jnp.arange(P, dtype=jnp.int32))
+    st0 = kpair.pair_state_init(snap, static.sig_match)
+    invol, has_pair = ka._sig_involvement(snap, static, st0)
+    lo = meta.pod_names.index("lo")
+    hi = meta.pod_names.index("hi")
+    init = (snap.nodes.used, jnp.full(P, -1, jnp.int32), st0,
+            jnp.zeros(P, bool).at[lo].set(True),
+            jnp.full(P, ka.NEG_INF, jnp.float32), jnp.full(P, -1, jnp.int32),
+            jnp.array(True), jnp.int32(0), jnp.int32(0))
+    out = ka._solve_rounds_sig(cfg, snap, static, rank, order, invol,
+                               has_pair, init, 2 * P + 8,
+                               ka._fallback_depth(N), 0)
+    assigned, round_of = np.asarray(out[1]), np.asarray(out[5])
+    assert assigned[lo] >= 0 and assigned[hi] >= 0
+    assert assigned[lo] != assigned[hi]
+    assert (round_of[lo], round_of[hi]) == (0, 1)

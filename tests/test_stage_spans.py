@@ -10,6 +10,7 @@ from tpusched import EngineConfig, trace
 from tpusched.rpc.client import DeltaSession, SchedulerClient
 from tpusched.rpc.codec import snapshot_to_proto
 from tpusched.rpc.server import make_server
+from tpusched.snapshot import MatchExpression, TopologySpreadConstraint
 
 CLIENT = ("client.check", "client.diff", "client.remember")
 APPLY = ("apply.records", "apply.encode", "apply.order", "apply.scatter")
@@ -152,3 +153,56 @@ def test_laps_record_back_to_back_stages(cat):
     off = trace.TraceCollector(enabled=False).laps(cat)
     off.lap("a")
     off.close()
+
+
+def _spread_contended():
+    """Twelve foo=bar pods, each spread with maxSkew 1 over hostname and
+    zone, onto five nodes in three zones: the round deals more of them
+    than the skews allow, and validation takes the excess back."""
+    sel = (MatchExpression("foo", "In", ("bar",)),)
+    nodes = [dict(name=f"n{j}", allocatable={"cpu": 8000.0,
+                                              "memory": float(32 << 30)},
+                  labels={"kubernetes.io/hostname": f"n{j}",
+                          "zone": f"z{j % 3}"}) for j in range(5)]
+    pods = [dict(name=f"p{i:02d}",
+                 requests={"cpu": 100.0 + 10 * (i % 3),
+                           "memory": float(1 << 20)},
+                 labels={"foo": "bar"},
+                 topology_spread=[TopologySpreadConstraint(
+                     topology_key=key, max_skew=1,
+                     when_unsatisfiable="DoNotSchedule", selector=sel)
+                     for key in ("kubernetes.io/hostname", "zone")])
+            for i in range(12)]
+    return snapshot_to_proto(nodes, pods)
+
+
+def _joins(col, msg):
+    server, port, svc = make_server("127.0.0.1:0",
+                                    config=EngineConfig(mode="fast"),
+                                    tracer=col)
+    server.start()
+    client = SchedulerClient(f"127.0.0.1:{port}", tracer=col)
+    try:
+        col.clear()
+        resp = DeltaSession(client).assign(msg, packed_ok=True)
+        return resp, [s for s in col.spans() if s.name == "fetch.join"]
+    finally:
+        client.close()
+        server.stop(0)
+        svc.close()
+
+
+def test_fetch_join_carries_rounds_and_no_reverts_without_signatures(
+        thread_leak_check):
+    col = trace.TraceCollector(seed=29)
+    for spans in _drive(col):
+        (join,) = [s for s in spans if s.name == "fetch.join"]
+        assert join.attrs["rounds"] >= 1
+        assert join.attrs["reverts"] == 0
+
+
+def test_fetch_join_counts_the_pairwise_reverts(thread_leak_check):
+    col = trace.TraceCollector(seed=31)
+    resp, (join,) = _joins(col, _spread_contended())
+    assert join.attrs["rounds"] == resp.rounds > 1
+    assert join.attrs["reverts"] > 0

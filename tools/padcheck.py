@@ -288,7 +288,7 @@ def _harnesses() -> List[Harness]:
             cfg, snap, tab, jnp.asarray(carry), jnp.asarray(chosen),
             jnp.asarray(frontier), None, cap=8,
         )
-        assigned, chosen_o, _used, _order, _ro, _r, _ev, audit = res
+        assigned, chosen_o, _used, _order, _ro, _r, _ev, _rv, audit = res
         return {
             "assignment": np.asarray(assigned)[:P],
             "chosen_score": np.asarray(chosen_o)[:P],
@@ -556,7 +556,7 @@ def _mesh_cases() -> List[Harness]:
             cfg, snap, tab, ship(carry), ship(chosen), ship(frontier),
             None, cap=8, mesh=mesh,
         )
-        assigned, chosen_o, _used, _order, _ro, _r, _ev, audit = res
+        assigned, chosen_o, _used, _order, _ro, _r, _ev, _rv, audit = res
         return {"assignment": np.asarray(assigned)[:P],
                 "chosen_score": np.asarray(chosen_o)[:P],
                 "audit": np.asarray(audit)}

@@ -53,6 +53,9 @@ class SolveResult:
     # (parity: pop-order position; fast: round index). -1 = unplaced.
     commit_key: np.ndarray | None = None
     rounds: int = 0            # commit rounds (fast mode; P for parity)
+    # Fast mode with signatures: commits the rounds' pairwise validation
+    # rolled back, summed over the rounds (0 on every other path).
+    reverts: int = 0
     # [M] bool: running pods evicted by preemption (cfg.preemption);
     # the host must delete these before binding their preemptors.
     evicted: np.ndarray | None = None
@@ -211,13 +214,13 @@ def _sat_tables(snap: ClusterSnapshot, mesh=None):
 def solve_core(cfg: EngineConfig, snap: ClusterSnapshot, mesh=None,
                explain: bool = False, static=None, member_sat_t=None):
     """Mode dispatch shared by Engine and tenants.solve_many: returns
-    (assigned, chosen, used, order, commit_key, rounds, evicted) in
-    either mode (parity synthesizes commit_key from pop order and
-    rounds=P). With cfg.ring_counts and a multi-device mesh, the
-    initial pairwise domain counts come from the blockwise ring kernel
-    (sig blocks rotating over the 'p' axis via ppermute) instead of the
-    dense contraction — bit-identical results, O(S/ndev x members/ndev)
-    peak memory (SURVEY.md §2.3 SP/CP row).
+    (assigned, chosen, used, order, commit_key, rounds, evicted,
+    reverts) in either mode (parity synthesizes commit_key from pop
+    order, rounds=P and reverts=0). With cfg.ring_counts and a
+    multi-device mesh, the initial pairwise domain counts come from the
+    blockwise ring kernel (sig blocks rotating over the 'p' axis via
+    ppermute) instead of the dense contraction — bit-identical results,
+    O(S/ndev x members/ndev) peak memory (SURVEY.md §2.3 SP/CP row).
 
     explain=True (decision provenance, round 12) appends one trailing
     tuple (rolled, evictor, evict_round, auction_stats) — see
@@ -254,7 +257,7 @@ def solve_core(cfg: EngineConfig, snap: ClusterSnapshot, mesh=None,
     rank = jnp.zeros(P, jnp.int32).at[o].set(
         jnp.arange(P, dtype=jnp.int32)
     )
-    base = (a, c, u, o, rank, jnp.int32(P), ev)
+    base = (a, c, u, o, rank, jnp.int32(P), ev, jnp.int32(0))
     return base + ((extras,) if explain else ())
 
 
@@ -269,11 +272,12 @@ def _pack_solve(out, mesh=None):
     partitioner mis-routes (tpusched/shardctx.py) — so on a mesh the
     result is pinned replicated (it is about to be fetched to the host
     wholesale anyway)."""
-    assigned, chosen, used, order, commit_key, rounds, ev = out
+    assigned, chosen, used, order, commit_key, rounds, ev, reverts = out
     return constrain_replicated(jnp.concatenate([
         assigned.astype(jnp.float32), chosen,
         order.astype(jnp.float32), commit_key.astype(jnp.float32),
         used.reshape(-1), ev.astype(jnp.float32),
+        reverts.astype(jnp.float32)[None],
         rounds.astype(jnp.float32)[None],
     ]), mesh)
 
@@ -492,6 +496,7 @@ class Engine:
             commit_key=buf[3 * P : 4 * P].astype(np.int32),
             final_used=buf[4 * P : base].reshape(N, R),
             evicted=buf[base : base + M] > 0,
+            reverts=int(buf[-2]),
             rounds=int(buf[-1]),
         )
 
@@ -653,7 +658,7 @@ class Engine:
                                         carry_chosen, frontier, dnode,
                                         _cap, mesh=mesh)
                 return constrain_replicated(jnp.concatenate(
-                    [_pack_solve(out[:7], mesh), out[7]]), mesh), tab
+                    [_pack_solve(out[:8], mesh), out[8]]), mesh), tab
 
             fn = self._warm_inc_jits[cap] = self._traced_jit(
                 f"warm_incremental_cap{cap}", _inc)
@@ -852,7 +857,7 @@ class Engine:
         P = snap.pods.valid.shape[0]
         N, R = snap.nodes.used.shape
         M = snap.running.valid.shape[0]
-        std = 4 * P + N * R + M + 1
+        std = 4 * P + N * R + M + 2
         res = Engine.unpack(snap, buf[:std])
         off = std
         rolled = buf[off:off + P] > 0
@@ -882,12 +887,13 @@ class Engine:
         if self._explain_solve_jit is None:
             def _packed_explained(s: ClusterSnapshot):
                 out = solve_core(cfg, s, mesh=mesh, explain=True)
-                a, c, u, o, ck, rounds, ev = out[:7]
-                rolled, evictor, evict_rd, astats = out[7]
+                a, c, u, o, ck, rounds, ev, reverts = out[:8]
+                rolled, evictor, evict_rd, astats = out[8]
                 return jnp.concatenate([
                     a.astype(jnp.float32), c, o.astype(jnp.float32),
                     ck.astype(jnp.float32), u.reshape(-1),
                     ev.astype(jnp.float32),
+                    reverts.astype(jnp.float32)[None],
                     rounds.astype(jnp.float32)[None],
                     rolled.astype(jnp.float32),
                     evictor.astype(jnp.float32),

@@ -582,6 +582,16 @@ def _spread_waterfill_deal(snap: ClusterSnapshot, pair_st, used, relaxed,
     because its counts move per pod, and the fast mode escapes it here
     by targeting against end-of-round semantics and letting the skew
     validator (which checks exactly that state) confirm or revert.
+    A member with several DoNotSchedule constraints is water-filled on
+    its COARSEST one (fewest domains: the zone, not the hostname), and
+    fans out inside the dealt domain over the nodes its FINEST one
+    admits against start-state counts (the emptiest hosts). Water-
+    filling on the hostname alone left the zone unbalanced: with the
+    zones a few pods apart, every member landed in zones the
+    end-of-round zone skew rejects, round after round, while
+    start-state-feasible hosts stood empty in the emptiest zone
+    (scheduler_perf's spread template at 5k nodes).
+
     Returns (cand[P, K+1] int32, val[P, K+1] f32 scores at those
     candidates, ok[P] bool); ok=False falls back to the capacity
     dealer's choice (e.g. no relax-feasible node in the domain)."""
@@ -598,17 +608,25 @@ def _spread_waterfill_deal(snap: ClusterSnapshot, pair_st, used, relaxed,
     LARGE = jnp.float32(1e9)  # finite stand-in for "domain absent"
     dom_s = kpair.sig_domains(snap)                          # [S, N]
     N = dom_s.shape[1]
+    exist = jnp.zeros((S, N), bool).at[
+        jnp.arange(S)[:, None], jnp.clip(dom_s, 0, None)
+    ].max(dom_s >= 0)
+    n_dom = jnp.sum(exist.astype(jnp.int32), axis=1)         # [S]
     # Members are pods with a DoNotSchedule constraint, keyed by their
-    # FIRST DNS slot — that is the filter that serializes them.
-    # ScheduleAnyway-only pods keep the normal score-driven dealing
-    # (their spread score already penalizes crowded domains, and the
-    # skew validator never reverts them).
+    # coarsest DNS slot (ties: the first) and fanned out by their finest
+    # (see the docstring). ScheduleAnyway-only pods keep the normal
+    # score-driven dealing (their spread score already penalizes
+    # crowded domains, and the skew validator never reverts them).
     dns = pods.ts_valid & (pods.ts_when == DO_NOT_SCHEDULE)
     has_dns = jnp.any(dns, axis=1)
-    first_c = jnp.argmax(dns, axis=1)
-    s_p = jnp.clip(
-        pods.ts_sig[jnp.arange(P), first_c], 0, None
-    )                                                        # [P]
+    sig_c = jnp.clip(pods.ts_sig, 0, None)                   # [P, C]
+    nd_c = n_dom[sig_c]
+    coarse_c = jnp.argmin(jnp.where(dns, nd_c, BIG), axis=1)
+    fine_c = jnp.argmax(jnp.where(dns, nd_c, -1), axis=1)
+    rows = jnp.arange(P)
+    s_p = sig_c[rows, coarse_c]                              # [P]
+    s_fine = sig_c[rows, fine_c]
+    has_fine = has_dns & (fine_c != coarse_c)
     member = allowed & has_dns
     # In-sig 0-based rank positions among this round's members.
     gid = jnp.where(member, s_p, S)
@@ -627,9 +645,6 @@ def _spread_waterfill_deal(snap: ClusterSnapshot, pair_st, used, relaxed,
     q_sorted = cum - q_off - 1.0                             # 0-based
     q = jnp.zeros(P, jnp.float32).at[perm].set(q_sorted)
     # Per-sig water-fill tables over the domain-count rows.
-    exist = jnp.zeros((S, N), bool).at[
-        jnp.arange(S)[:, None], jnp.clip(dom_s, 0, None)
-    ].max(dom_s >= 0)
     cnt = jnp.where(exist, pair_st.counts, LARGE)            # [S, N]
     ord_dom = jnp.argsort(cnt, axis=1)                       # [S, N]
     csort = jnp.take_along_axis(cnt, ord_dom, axis=1)
@@ -654,7 +669,19 @@ def _spread_waterfill_deal(snap: ClusterSnapshot, pair_st, used, relaxed,
         ord_dom[s_p], slot[:, None], axis=1
     )[:, 0]                                                  # [P] domain id
     in_dom = dom_s[s_p] == dchoice[:, None]                  # [P, N]
-    sel = relaxed & in_dom
+    # Inside the domain, only nodes whose FINEST constraint's domain is
+    # under its start-state bound (count + 1 <= min + maxSkew): the
+    # emptiest hosts of the dealt zone, not any host of it. Row gathers
+    # of [S, N] tables only: a per-row [P, N] permutation (an order of
+    # the hosts by count) cost ~80 ms a round at 5k x 5k on the chip.
+    min_s = jnp.min(cnt, axis=1)                             # [S]
+    node_cnt = jnp.take_along_axis(
+        pair_st.counts, jnp.clip(dom_s, 0, None), axis=1
+    )                                                        # [S, N]
+    fine_ok = (node_cnt[s_fine] + 1.0 <= (
+        min_s[s_fine] + pods.ts_max_skew[rows, fine_c])[:, None]
+    ) & (dom_s[s_fine] >= 0)                                 # [P, N]
+    sel = relaxed & in_dom & (fine_ok | ~has_fine[:, None])
     # Within the domain, members must also fan out across NODES: the
     # best-scoring node is nearly the same for every member (the load
     # balancing scores barely separate them), and one node holds only a
@@ -662,15 +689,33 @@ def _spread_waterfill_deal(snap: ClusterSnapshot, pair_st, used, relaxed,
     # (observed: the commit rate stayed capacity-capped at ~15/round).
     # Member m of its (sig, domain) takes the (m mod n_feasible)-th
     # feasible domain node in free-capacity order, with the next K
-    # rotation positions as its spill candidates.
-    m_p = r_i // (j_p + 1)                                   # [P] level offset
+    # rotation positions as its spill candidates. m counts the members
+    # the fill dealt to the domain before it: those of the earlier
+    # levels (csort[j] - csort[slot]) and of this one.
+    m_p = (csort[s_p, j_p] - csort[s_p, slot]).astype(jnp.int32) + (
+        r_i // (j_p + 1))                                    # [P]
     alloc = snap.nodes.allocatable
     free_frac = jnp.mean(  # tpl: disable=TPL201(per-node mean over the FIXED R resource axis — cell-local, never padded or sharded; orders a dealing rotation that the capacity-prefix commit validates)
         jnp.where(alloc > 0, (alloc - used) / jnp.maximum(alloc, 1e-9), 0.0),
         axis=1,
     )                                                        # [N]
     cap_order = jnp.argsort(-free_frac).astype(jnp.int32)    # [N]
-    sel_sorted = sel[:, cap_order]                           # [P, N]
+    # Members whose finest signature is the one most members share (a
+    # template's replicas) rotate over the nodes in ascending count of
+    # it instead: emptiest hosts first, so the domain minimum rises as
+    # upstream's per-pod loop raises it, rather than drifting down until
+    # no host is under the bound. One order for all of them: a
+    # per-member order is a [P, N] per-row gather, ~80 ms a round at
+    # 5k x 5k on the chip.
+    use = jnp.zeros(S, jnp.int32).at[s_fine].add(
+        (member & has_fine).astype(jnp.int32))
+    s_top = jnp.argmax(use)
+    by_fine = has_fine & (s_fine == s_top)
+    fine_order = jnp.lexsort((
+        -free_frac, jnp.where(dom_s[s_top] >= 0, node_cnt[s_top], LARGE),
+    )).astype(jnp.int32)                                     # [N]
+    sel_sorted = jnp.where(by_fine[:, None], sel[:, fine_order],
+                           sel[:, cap_order])                 # [P, N]
     csum = jnp.cumsum(sel_sorted.astype(jnp.float32), axis=1)
     n_feas = csum[:, -1]
     targets = jnp.mod(
@@ -689,7 +734,9 @@ def _spread_waterfill_deal(snap: ClusterSnapshot, pair_st, used, relaxed,
         ],
         axis=1,
     )
-    cand = cap_order[jnp.clip(j_node, 0, cap_order.shape[0] - 1)]
+    j_node = jnp.clip(j_node, 0, N - 1)
+    cand = jnp.where(by_fine[:, None], fine_order[j_node],
+                     cap_order[j_node])
     ok = member & (n_feas > 0)
     sel_at = jnp.take_along_axis(sel, cand, axis=1)
     val = jnp.where(
@@ -1896,18 +1943,21 @@ def _solve_rounds_sig(cfg: EngineConfig, snap: ClusterSnapshot,
     twins incl. preemption and gang admission.
 
     init/returns: (used, assigned, pair_st, conservative, chosen,
-    round_of, progress, r) — `init` may carry a warm-seeded state (the
-    incremental path: carried assignments pre-committed into used and
-    pair_st, r starting past the carried commit key)."""
+    round_of, progress, r, reverts) — reverts: the commits validation
+    rolled back, summed over the rounds; `init` may carry a warm-seeded
+    state (the incremental path: carried assignments pre-committed into
+    used and pair_st, r starting past the carried commit key)."""
     pods, nodes = snap.pods, snap.nodes
     P = pods.valid.shape[0]
     N = nodes.valid.shape[0]
     BIG = jnp.int32(2**31 - 1)
 
     def round_math(snap_v, static_v, invol_v, hp_v, rank_v, pod_ids,
-                   pending_v, conservative_v, used, pair_st, r):
+                   pending_v, conservative_v, strict_v, used, pair_st, r):
         """One commit round over a (possibly compacted) pod-axis view.
-        Returns (used3, st3, kept, choice, chosen_val, fb_mask)."""
+        Returns (used3, st3, kept, choice, chosen_val, fb_mask,
+        strict_mask, reverted) — reverted: the commits validation
+        rolled back."""
         feasible, score, relaxed = batched_cycle(
             cfg, snap_v, static_v, used, pair_st, return_relaxed=True
         )
@@ -1929,13 +1979,23 @@ def _solve_rounds_sig(cfg: EngineConfig, snap: ClusterSnapshot,
                       True),
             axis=1,
         )
-        allowed = want & (~conservative_v | ok_cons)
+        # A conservative pod that leads its clusters commits ALONE in
+        # them: the other pods of its signatures sit the round out, so
+        # nothing this round moves the counts it was filtered against
+        # and validation keeps it (the backstop's progress guarantee).
+        lead = cons_want & ok_cons
+        held = jnp.any(invol_v & lead[:, None], axis=0)         # [S]
+        wait = ~conservative_v & jnp.any(invol_v & held[None, :], axis=1)
+        allowed = want & (~conservative_v | ok_cons) & ~wait
 
         # Water-fill membership and activation use the RELAXED rows: a
         # DNS pod whose every in-bound domain is skew-blocked against
         # round-start counts can still legally place under end-of-round
         # semantics (the validator's state) — see _spread_waterfill_deal.
-        allowed_r = jnp.any(relaxed, axis=1) & (~conservative_v | ok_cons)
+        # Pods the progress backstop made strict deal by the start-state
+        # filter instead (see below).
+        allowed_r = (jnp.any(relaxed, axis=1) & ~strict_v & ~wait
+                     & (~conservative_v | ok_cons))
         sp_cand, sp_val, sp_ok = _spread_waterfill_deal(
             snap_v, pair_st, used, relaxed, score, allowed_r, rank_v, K
         )
@@ -2036,22 +2096,33 @@ def _solve_rounds_sig(cfg: EngineConfig, snap: ClusterSnapshot,
         # next round's start-state counts (which now mask the domains
         # they lost), so they normally converge without any gating. But
         # if EVERY commit of this round was reverted, optimism alone
-        # proves nothing placed — mark the first reverted pod (by rank)
-        # conservative so the ordered one-per-cluster path guarantees
-        # progress.
+        # proves nothing placed. The water-fill targets end-of-round
+        # counts and can deal a pod to the same rejected domain again
+        # while start-state-feasible nodes stand free, so first every
+        # reverted water-filled pod turns strict (leaves the water-fill
+        # for the capacity dealer over its start-state-feasible nodes,
+        # all at once). Only when no reverted pod was water-filled is
+        # the first by rank marked conservative: the ordered
+        # one-per-cluster path then commits it alone in its clusters on
+        # a start-state-feasible node, which validation keeps.
         need_fb = ~jnp.any(kept) & jnp.any(viol)
+        st_mask = viol & sp_ok & need_fb
         fb_first = rank_v == jnp.min(jnp.where(viol, rank_v, BIG))
-        fb_mask = viol & fb_first & need_fb
-        return used3, st3, kept, choice, chosen_val, fb_mask
+        fb_mask = viol & fb_first & need_fb & ~jnp.any(st_mask)
+        reverted = jnp.sum(viol.astype(jnp.int32))
+        return (used3, st3, kept, choice, chosen_val, fb_mask, st_mask,
+                reverted)
 
     ids = jnp.arange(P, dtype=jnp.int32)
 
     def full_body(state):
-        used, assigned, pair_st, conservative, chosen, round_of, _, r = state
+        (used, assigned, pair_st, conservative, chosen, round_of, _, r,
+         reverts, strict) = state
         pending = assigned == -1
-        used3, st3, kept, choice, chosen_val, fb_mask = round_math(
+        (used3, st3, kept, choice, chosen_val, fb_mask, st_mask,
+         rv) = round_math(
             snap, static, invol, has_pair, rank, ids, pending,
-            conservative, used, pair_st, r,
+            conservative, strict, used, pair_st, r,
         )
         assigned2 = jnp.where(kept, choice, assigned)
         chosen2 = jnp.where(kept, chosen_val, chosen)
@@ -2059,12 +2130,13 @@ def _solve_rounds_sig(cfg: EngineConfig, snap: ClusterSnapshot,
         new_conservative = fb_mask & ~conservative
         conservative2 = conservative | fb_mask
         all_done = jnp.all((assigned2 >= 0) | ~pods.valid)
-        progress = (jnp.any(kept) | jnp.any(new_conservative)) & ~all_done
+        progress = (jnp.any(kept) | jnp.any(new_conservative)
+                    | jnp.any(st_mask)) & ~all_done
         return (used3, assigned2, st3, conservative2, chosen2,
-                round_of2, progress, r + 1)
+                round_of2, progress, r + 1, reverts + rv, strict | st_mask)
 
     def full_cond(state):
-        progress, r = state[-2], state[-1]
+        progress, r = state[6], state[7]
         ok = progress & (r < max_rounds)
         if cap:
             # Hand off to the compacted loop once the whole pending
@@ -2075,18 +2147,22 @@ def _solve_rounds_sig(cfg: EngineConfig, snap: ClusterSnapshot,
             ) > cap
         return ok
 
-    state = jax.lax.while_loop(full_cond, full_body, init)
+    # The backstop's strict marks (see round_math) live only here.
+    state = jax.lax.while_loop(full_cond, full_body,
+                               init + (jnp.zeros(P, bool),))
     if not cap:
-        return state
+        return state[:-1]
 
     def compact_body(state):
-        used, assigned, pair_st, conservative, chosen, round_of, _, r = state
+        (used, assigned, pair_st, conservative, chosen, round_of, _, r,
+         reverts, strict) = state
         pend = (assigned == -1) & pods.valid
         sel, _ = _top_by_rank(pend, order, cap)
         snap_v, static_v = _pods_view(snap, static, sel)
-        used3, st3, kept, choice, chosen_val, fb_mask = round_math(
+        (used3, st3, kept, choice, chosen_val, fb_mask, st_mask,
+         rv) = round_math(
             snap_v, static_v, invol[sel], has_pair[sel], rank[sel], sel,
-            pend[sel], conservative[sel], used, pair_st, r,
+            pend[sel], conservative[sel], strict[sel], used, pair_st, r,
         )
         assigned2 = assigned.at[sel].set(
             jnp.where(kept, choice, assigned[sel])
@@ -2101,28 +2177,32 @@ def _solve_rounds_sig(cfg: EngineConfig, snap: ClusterSnapshot,
         conservative2 = conservative.at[sel].set(
             conservative[sel] | fb_mask
         )
+        strict2 = strict.at[sel].set(strict[sel] | st_mask)
         all_done = jnp.all((assigned2 >= 0) | ~pods.valid)
-        progress = (jnp.any(kept) | jnp.any(new_conservative)) & ~all_done
+        progress = (jnp.any(kept) | jnp.any(new_conservative)
+                    | jnp.any(st_mask)) & ~all_done
         return (used3, assigned2, st3, conservative2, chosen2,
-                round_of2, progress, r + 1)
+                round_of2, progress, r + 1, reverts + rv, strict2)
 
     def compact_cond(state):
-        progress, r = state[-2], state[-1]
+        progress, r = state[6], state[7]
         return progress & (r < max_rounds)
 
-    return jax.lax.while_loop(compact_cond, compact_body, state)
+    return jax.lax.while_loop(compact_cond, compact_body, state)[:-1]
 
 
 def solve_rounds(cfg: EngineConfig, snap: ClusterSnapshot,
                  node_sat_t, member_sat_t, init_counts=None,
                  explain: bool = False, static=None, mesh=None):
     """Fast mode: optimistic batched rounds with validate-and-rollback.
-    Returns (assigned, chosen, used, order, round_of, rounds, evicted);
-    with explain=True (decision provenance, round 12) an extra trailing
-    tuple (rolled, evictor, evict_round, auction_stats) — gang-rollback
-    mask [P], per-victim preemptor pod index / commit-round [M] (-1 =
-    not evicted), and the [_PREEMPT_MAX_ROUNDS, EXPLAIN_AUCTION_STATS]
-    per-round auction table. The explain accumulation is traced only
+    Returns (assigned, chosen, used, order, round_of, rounds, evicted,
+    reverts) — reverts: the commits the signature rounds' validation
+    rolled back (0 with no signatures); with explain=True (decision
+    provenance, round 12) an extra trailing tuple (rolled, evictor,
+    evict_round, auction_stats) — gang-rollback mask [P], per-victim
+    preemptor pod index / commit-round [M] (-1 = not evicted), and the
+    [_PREEMPT_MAX_ROUNDS, EXPLAIN_AUCTION_STATS] per-round auction
+    table. The explain accumulation is traced only
     when requested, so the default program is unchanged. static:
     optional precomputed StaticCtx (the warm path)."""
     if static is None:
@@ -2152,17 +2232,19 @@ def solve_rounds(cfg: EngineConfig, snap: ClusterSnapshot,
             cfg, snap, static, rank, order, max_rounds, K
         )
         st_f = st0
+        reverts = jnp.int32(0)
     else:
         init = (
             nodes.used, jnp.full(P, -1, jnp.int32), st0,
             jnp.zeros(P, bool), jnp.full(P, NEG_INF, jnp.float32),
             jnp.full(P, -1, jnp.int32), jnp.array(True), jnp.int32(0),
+            jnp.int32(0),
         )
         out = _solve_rounds_sig(
             cfg, snap, static, rank, order, invol, has_pair, init,
             max_rounds, K, _compact_cap(cfg, P),
         )
-        used, assigned, st_f, _, chosen, round_of, _, rounds = out
+        used, assigned, st_f, _, chosen, round_of, _, rounds, reverts = out
     M = snap.running.valid.shape[0]
     evicted = jnp.zeros(M, bool)
     evictor = evict_rd = astats = None
@@ -2192,7 +2274,8 @@ def solve_rounds(cfg: EngineConfig, snap: ClusterSnapshot,
     # Commit key for external validity audits: pods committed in earlier
     # rounds precede later ones; within a round all commits share a key
     # (the engine validated them against end-of-round state).
-    base = (assigned, chosen, used, order, round_of, rounds, evicted)
+    base = (assigned, chosen, used, order, round_of, rounds, evicted,
+            reverts)
     if explain:
         return base + ((rolled, evictor, evict_rd, astats),)
     return base
@@ -2280,7 +2363,7 @@ def solve_incremental(cfg: EngineConfig, snap: ClusterSnapshot, tab,
     full-width).
 
     Returns (assigned, chosen, used, order, round_of, rounds, evicted,
-    audit[INC_AUDIT_LEN] f32)."""
+    reverts, audit[INC_AUDIT_LEN] f32)."""
     static = finalize_static(cfg, snap, tab)
     pods, nodes = snap.pods, snap.nodes
     P = pods.valid.shape[0]
@@ -2358,14 +2441,15 @@ def solve_incremental(cfg: EngineConfig, snap: ClusterSnapshot, tab,
             skip_full=True, cap=(cap if cap > 0 else None),
         )
         st_f = st
+        reverts = jnp.int32(0)
     else:
         init = (used, assigned, st, jnp.zeros(P, bool), chosen, round_of,
-                jnp.array(True), jnp.int32(1))
+                jnp.array(True), jnp.int32(1), jnp.int32(0))
         out = _solve_rounds_sig(
             cfg, snap, static, rank, order, invol, has_pair, init,
             max_rounds, K, cap,
         )
-        used, assigned, st_f, _, chosen, round_of, _, rounds = out
+        used, assigned, st_f, _, chosen, round_of, _, rounds, reverts = out
     M = snap.running.valid.shape[0]
     evicted = jnp.zeros(M, bool)
     if cfg.preemption and M > 0:
@@ -2412,4 +2496,4 @@ def solve_incremental(cfg: EngineConfig, snap: ClusterSnapshot, tab,
         jnp.asarray(p_viol, jnp.float32), carried_n, frontier_n,
     ])
     return (assigned, chosen, used, order, round_of, rounds, evicted,
-            audit)
+            reverts, audit)

@@ -1357,16 +1357,24 @@ class SchedulerService:
         self._trace.record(stage, dur_s=dur, cat="server")
         self.metrics.observe_stage(stage, dur)
 
-    def _join_guarded(self, pending, what: str):
+    def _join_guarded(self, pending, what: str, solved=None):
         """Join a device result under the per-dispatch watchdog. A
         timeout converts the hung solve into DEADLINE_EXCEEDED for THIS
         caller, demotes the ladder, and abandons the wedged fetch
         worker so later dispatches get a live one (throttled: N callers
-        waiting on the same wedged worker trigger ONE restart)."""
+        waiting on the same wedged worker trigger ONE restart).
+        solved: for an Assign solve, picks its SolveResult out of the
+        result; the span then carries the solve's commit `rounds` and
+        the `reverts` its pairwise validation made (the benchmark's
+        kernel counters)."""
         t0 = time.perf_counter()
         try:
-            with self._trace.span("fetch.join", cat="server", what=what):
+            with self._trace.span("fetch.join", cat="server",
+                                  what=what) as sp:
                 res = pending.result(timeout=self.watchdog_s)
+                if solved is not None:
+                    one = solved(res)
+                    sp.attrs.update(rounds=one.rounds, reverts=one.reverts)
             self.metrics.observe_stage("fetch.join",
                                        time.perf_counter() - t0)
             return res
@@ -2033,9 +2041,11 @@ class SchedulerService:
         exd = None
         try:
             if explain_on:
-                res, exd = self._join_guarded(pending, "Assign solve")
+                res, exd = self._join_guarded(pending, "Assign solve",
+                                              solved=lambda r: r[0])
             else:
-                res = self._join_guarded(pending, "Assign solve")
+                res = self._join_guarded(pending, "Assign solve",
+                                         solved=lambda r: r)
         except BaseException:
             if warm_path != "cold" and session is not None:
                 # The conservative reset the warm contract demands: a

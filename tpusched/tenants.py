@@ -68,7 +68,7 @@ def stack_snapshots(snaps: list[ClusterSnapshot]) -> ClusterSnapshot:
 
 
 def _solve_one(cfg: EngineConfig, snap: ClusterSnapshot):
-    a, c, u, o, _, rounds, ev = solve_core(cfg, snap)
+    a, c, u, o, _, rounds, ev, _ = solve_core(cfg, snap)
     return a, c, u, o, rounds, ev
 
 
