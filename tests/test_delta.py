@@ -44,9 +44,12 @@ def _cluster_msg(n_pods=8, n_nodes=4, seed=0):
 
 @pytest.mark.parametrize("named", [False, True])
 def test_delta_walk_counts_the_records_examined(named):
-    """`walk` reports what the diff's loops examined: every record of the
-    new snapshot, then every base record scanned for removals — with or
-    without `changed` naming the churn."""
+    """`walk` reports what the diff examined one by one, then what it
+    serialized. Without `changed`: every record of the new snapshot,
+    then every base record scanned for removals, each new record
+    serialized. With `changed` naming the churn: each record's name,
+    read once, plus the touched records serialized — none here, since
+    the one named record was removed and removals come from the names."""
     nodes, pods, running = _cluster_msg()
     store = SnapshotStore(snapshot_to_proto(nodes, pods, running))
     pods2 = [p for p in pods if p["name"] != "p0"]
@@ -55,8 +58,12 @@ def test_delta_walk_counts_the_records_examined(named):
     delta = delta_between(store, new, "snap-0",
                           changed={"p0"} if named else None, walk=walk)
     assert list(delta.remove_pods) == ["p0"]
-    assert walk == [len(nodes) * 2 + len(pods2) + len(pods)
-                    + len(running) * 2]
+    n_new = len(nodes) + len(pods2) + len(running)
+    if named:
+        assert walk == [n_new, 0]
+    else:
+        assert walk == [len(nodes) * 2 + len(pods2) + len(pods)
+                        + len(running) * 2, n_new]
 
 
 def test_store_delta_roundtrip():

@@ -15,7 +15,7 @@ from tpusched.snapshot import MatchExpression, TopologySpreadConstraint
 CLIENT = ("client.check", "client.diff", "client.remember")
 APPLY = ("apply.records", "apply.encode", "apply.order", "apply.scatter")
 NEW = CLIENT + ("store.advance",) + APPLY
-ATTRS = {"client.diff": {"visited", "shipped"}}
+ATTRS = {"client.diff": {"visited", "serialized", "shipped"}}
 EPS = 2e-3   # wall start + perf_counter duration: a clock-mix epsilon
 
 
@@ -75,11 +75,14 @@ def test_delta_cycles_carry_the_stage_spans(thread_leak_check):
             assert name in by_name, (name, sorted(by_name))
             attrs = {k for s in by_name[name] for k in s.attrs}
             assert attrs == ATTRS.get(name, set()), (name, attrs)
-        # The diff's loops examine every record of the new snapshot and
-        # of the base (4 nodes, 6 pods, 3 + k running pods on each side).
+        # The named diff reads each record's name of the new snapshot
+        # once (4 nodes, 6 pods, 3 + k running pods) and serializes only
+        # the touched records: the bumped node and the two arrivals. It
+        # ships those and the bound pod's removal.
         diff = by_name["client.diff"][0].attrs
-        assert diff["visited"] == (4 + 6 + 3 + k) + (4 + 6 + 2 + k)
-        assert 0 < diff["shipped"] < diff["visited"]
+        assert diff["serialized"] == 3
+        assert diff["visited"] == (4 + 6 + 3 + k) + diff["serialized"]
+        assert diff["shipped"] == 4
         # One trace per cycle: the client's stages, the request and every
         # sidecar span share the id the client minted.
         rid = by_name["client.check"][0].trace_id

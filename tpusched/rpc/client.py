@@ -648,6 +648,7 @@ class DeltaSession:
         rid = ctx[0] if ctx is not None and ctx[0] else \
             tracer.new_trace_id()
         safe = False
+        names = None
         if (
             self._base is not None
             and self._base_id is not None
@@ -657,25 +658,37 @@ class DeltaSession:
             # name-keyed store would silently collapse unnamed/duplicate
             # records arriving as delta upserts and solve a corrupted
             # snapshot for this cycle. (_remember only drops the base
-            # for the NEXT cycle — one cycle too late.)
+            # for the NEXT cycle — one cycle too late.) On the named
+            # path this is the diff's one name pass.
             with tracer.span("client.check", cat="client", trace_id=rid):
-                safe = codec.delta_safe(snapshot)
+                if changed is None:
+                    safe = codec.delta_safe(snapshot)
+                else:
+                    names = codec.record_names(snapshot)
+                    safe = names is not None
         if safe:
-            new_bytes = codec.SnapshotStore()
+            # Named: an O(churn) update of the base, applied once the
+            # sidecar acknowledges. Unnamed: the next base, rebuilt.
+            update: "list[dict] | None" = None if changed is None else []
+            new_bytes = codec.SnapshotStore() if changed is None else None
             walk: "list[int]" = []
             with tracer.span("client.diff", cat="client",
                              trace_id=rid) as sp:
                 delta = codec.delta_between(
                     self._base, snapshot, self._base_id,
                     new_bytes=new_bytes, changed=changed, walk=walk,
+                    names=names, update=update,
                 )
+                # Free the name lists before the send: alive through it,
+                # they tripled the young collections' cost there.
+                names = None
                 self._seq += 1
                 delta.lineage_id = self._lineage_id
                 delta.seq = self._seq
                 delta_bytes = delta.ByteSize()
-                # Records the diff examined, and records it shipped.
+                # Records the diff examined, serialized, and shipped.
                 sp.attrs.update(
-                    visited=walk[0],
+                    visited=walk[0], serialized=walk[1],
                     shipped=len(delta.upsert_nodes) + len(delta.upsert_pods)
                     + len(delta.upsert_running) + len(delta.remove_nodes)
                     + len(delta.remove_pods) + len(delta.remove_running))
@@ -686,7 +699,8 @@ class DeltaSession:
                 self._consec_fallbacks = 0
                 with tracer.span("client.remember", cat="client",
                                  trace_id=rid):
-                    self._remember(snapshot, resp.snapshot_id, new_bytes)
+                    self._remember(snapshot, resp.snapshot_id, new_bytes,
+                                   update=update)
                 return resp
             except grpc.RpcError as e:
                 if e.code() != grpc.StatusCode.FAILED_PRECONDITION:
@@ -726,19 +740,25 @@ class DeltaSession:
 
     def _remember(self, snapshot: pb.ClusterSnapshot, sid: str,
                   prebuilt: "codec.SnapshotStore | None" = None,
-                  verified: bool = False) -> None:
+                  verified: bool = False,
+                  update: "list[dict] | None" = None) -> None:
         """Record what was sent, as per-record BYTES: immune to the
         caller mutating its message in place afterwards, and usable only
         when the snapshot is delta-safe (unique non-empty names — the
         stores key by name). `prebuilt` reuses the bytes delta_between
-        already serialized for the diff (no second serialization pass)."""
-        # prebuilt/verified only arrive from paths that already checked
-        # delta_safe this cycle — don't re-scan all records.
-        if not sid or (prebuilt is None and not verified
+        already serialized for the diff (no second serialization pass);
+        `update` is a named diff's O(churn) update of the current base,
+        which the delta was diffed against."""
+        # prebuilt/verified/update only arrive from paths that already
+        # checked delta safety this cycle — don't re-scan all records.
+        if not sid or (prebuilt is None and update is None and not verified
                        and not codec.delta_safe(snapshot)):
             self._base = self._base_id = None
             return
-        if prebuilt is not None:
+        if update is not None:
+            self._base.apply_update(update)
+            st = self._base
+        elif prebuilt is not None:
             st = prebuilt
         else:
             st = codec.SnapshotStore()
@@ -963,17 +983,20 @@ class _BasePipeline:
         churn_next = (
             self._churn | set(changed) if changed is not None else None
         )
+        # One name pass: the safety check on both paths, and the named
+        # diff's names on the delta path.
+        names = codec.record_names(snapshot)
         if (
             self._pinned is None or self._pinned_id is None
             or churn_next is None
             or len(churn_next) > self.refresh_frac * max(n_rec, 1)
-            or not codec.delta_safe(snapshot)
+            or names is None
         ):
             done = self.flush()
             resp = self._send_full(snapshot, packed_ok)
             self.full_sends += 1
             self.bytes_sent += snapshot.ByteSize()
-            if resp.snapshot_id and codec.delta_safe(snapshot):
+            if resp.snapshot_id and names is not None:
                 st = codec.SnapshotStore()
                 st.set_full_bytes(snapshot)
                 self._pinned, self._pinned_id = st, resp.snapshot_id
@@ -984,8 +1007,10 @@ class _BasePipeline:
             return done
         self._churn = churn_next
         delta = codec.delta_between(
-            self._pinned, snapshot, self._pinned_id, changed=self._churn
+            self._pinned, snapshot, self._pinned_id, changed=self._churn,
+            names=names,
         )
+        names = None  # freed before the send, as in DeltaSession._call
         self._seq += 1
         delta.lineage_id = self._lineage_id
         delta.seq = self._seq

@@ -10,8 +10,10 @@ proto directly from its cache.
 from __future__ import annotations
 
 import logging
+import operator
 import os
 import traceback
+from itertools import compress, filterfalse
 
 from tpusched.config import (Buckets, DEFAULT_OBSERVED_AVAIL,
                              DEFAULT_SLO_TARGET, EngineConfig)
@@ -212,6 +214,9 @@ def snapshot_from_proto(
 # ---------------------------------------------------------------------------
 
 
+_NAME = operator.attrgetter("name")
+
+
 class UnknownBase(KeyError):
     """Delta referenced a base_id the store no longer holds."""
 
@@ -223,15 +228,26 @@ def _by_name(coll):
     return sorted(coll, key=lambda r: r.name)
 
 
+def record_names(msg: pb.ClusterSnapshot):
+    """Each collection's record names (nodes, pods, running), read once
+    in message order, as (names, name set) pairs; None when the snapshot
+    is not delta-safe (see delta_safe). The named diff takes these
+    pairs, so one name pass serves both the safety check and the diff."""
+    out = []
+    for coll in (msg.nodes, msg.pods, msg.running):
+        names = list(map(_NAME, coll))
+        name_set = set(names)
+        if "" in name_set or len(name_set) != len(names):
+            return None
+        out.append((names, name_set))
+    return tuple(out)
+
+
 def delta_safe(msg: pb.ClusterSnapshot) -> bool:
     """A snapshot is usable as a delta base only if every record carries
     a unique non-empty name: the stores key by name, so unnamed or
     duplicate records would silently collapse on the delta path."""
-    for coll in (msg.nodes, msg.pods, msg.running):
-        names = [r.name for r in coll]
-        if any(not n for n in names) or len(set(names)) != len(names):
-            return False
-    return True
+    return record_names(msg) is not None
 
 
 def _varint(n: int) -> bytes:
@@ -322,6 +338,17 @@ class SnapshotStore:
         for name in delta.remove_running:
             self.running.pop(name, None)
 
+    def apply_update(self, update) -> None:
+        """Apply a named diff's base update (delta_between's `update`):
+        per collection, name -> the record's new bytes, or None for a
+        removal. O(churn): the store becomes the diffed snapshot's."""
+        for d, upd in zip((self.nodes, self.pods, self.running), update):
+            for name, raw in upd.items():
+                if raw is None:
+                    d.pop(name, None)
+                else:
+                    d[name] = raw
+
     def compose(self) -> pb.ClusterSnapshot:
         msg = pb.ClusterSnapshot()
         if any(isinstance(v, bytes) for v in
@@ -362,7 +389,9 @@ def delta_between(prev: SnapshotStore, msg: pb.ClusterSnapshot,
                   base_id: str,
                   new_bytes: SnapshotStore | None = None,
                   changed: "set[str] | None" = None,
-                  walk: "list[int] | None" = None) -> pb.SnapshotDelta:
+                  walk: "list[int] | None" = None,
+                  names=None,
+                  update: "list[dict] | None" = None) -> pb.SnapshotDelta:
     """Client-side diff: the SnapshotDelta turning `prev` into `msg`.
     Record equality by serialized bytes. `prev` values may be messages
     or pre-serialized bytes (DeltaSession stores bytes so that a caller
@@ -375,55 +404,127 @@ def delta_between(prev: SnapshotStore, msg: pb.ClusterSnapshot,
 
     changed: optional set of record names the caller knows may have
     changed since the base (an informer-driven client knows exactly
-    which objects its watch events touched). Base records NOT named are
-    trusted byte-identical and skipped without re-serialization, making
-    the per-cycle diff O(churn) instead of O(cluster) serialization
-    work (~100 ms at 10k x 5k). Additions and removals are still
-    detected by name regardless. CONTRACT: a caller that mutates a
-    record without naming it here ships a stale record and the sidecar
-    solves a stale snapshot — name everything you touch.
+    which objects its watch events touched). The diff then reads each
+    record's name once and finds additions and removals by set
+    operations on the names; it serializes and compares only the
+    touched records — additions, and named records the base holds — in
+    message order. Base records NOT named are trusted byte-identical,
+    making the per-cycle diff O(churn) past the one name pass instead
+    of O(cluster) serialization and bookkeeping. Additions and removals
+    are still detected by name regardless. CONTRACT: a caller that
+    mutates a record without naming it here ships a stale record and
+    the sidecar solves a stale snapshot — name everything you touch.
+    Upserts keep message order; removes come in name order.
 
-    walk: optional list; when given, the number of records the diff's
-    loops examined (new records, then base records scanned for
-    removals) is appended to it — the scan the client pays per cycle."""
+    names: optional record_names(msg), when the caller already read the
+    names for its safety check (named path only; read here otherwise).
+    The snapshot must be delta-safe on the named path.
+
+    update: optional empty list; on the named path filled with one dict
+    per collection (nodes, pods, running): name -> the record's new
+    bytes, or None for a removal. `prev.apply_update(update)` turns
+    prev into msg's store in O(churn); prev itself is left as it is.
+
+    walk: optional list; when given, two counts are appended to it: the
+    records the diff examined one by one (with `changed`: the names
+    read plus the records serialized; without: the new records, then
+    the base records scanned for removals), then the records it
+    serialized and compared — the scan the client pays per cycle."""
     delta = pb.SnapshotDelta(base_id=base_id)
-    if changed is not None and not isinstance(changed, set):
-        changed = set(changed)
-    walked = 0
-
-    def diff(prev_d, coll, upserts, removes, out_d):
-        nonlocal walked
-        new_names = set()
-        n = 0
-        for n, rec in enumerate(coll, 1):
-            new_names.add(rec.name)
-            old = prev_d.get(rec.name)
-            if (changed is not None and old is not None
-                    and rec.name not in changed):
-                if out_d is not None:
-                    out_d[rec.name] = _ser(old)
-                continue
-            raw = rec.SerializeToString()
-            if out_d is not None:
-                out_d[rec.name] = raw
-            if old is None or _ser(old) != raw:
-                upserts.append(rec)
-        m = 0
-        for m, k in enumerate(prev_d, 1):
-            if k not in new_names:
-                removes.append(k)
-        walked += n + m
-
-    nb = new_bytes
-    diff(prev.nodes, msg.nodes, delta.upsert_nodes, delta.remove_nodes,
-         nb.nodes if nb else None)
-    diff(prev.pods, msg.pods, delta.upsert_pods, delta.remove_pods,
-         nb.pods if nb else None)
-    diff(prev.running, msg.running, delta.upsert_running,
-         delta.remove_running, nb.running if nb else None)
+    colls = (
+        (prev.nodes, msg.nodes, delta.upsert_nodes, delta.remove_nodes),
+        (prev.pods, msg.pods, delta.upsert_pods, delta.remove_pods),
+        (prev.running, msg.running, delta.upsert_running,
+         delta.remove_running),
+    )
+    if changed is None:
+        walked = serialized = 0
+        outs = ((new_bytes.nodes, new_bytes.pods, new_bytes.running)
+                if new_bytes is not None else (None,) * 3)
+        for (prev_d, coll, upserts, removes), out_d in zip(colls, outs):
+            w, n = _full_diff(prev_d, coll, upserts, removes, out_d)
+            walked += w
+            serialized += n
+    else:
+        if not isinstance(changed, (set, frozenset)):
+            changed = set(changed)
+        if names is None:
+            names = record_names(msg)
+            if names is None:
+                raise ValueError("the named diff needs a delta-safe "
+                                 "snapshot: unique non-empty names")
+        upd: "list[dict]" = []
+        walked = serialized = 0
+        for (prev_d, coll, upserts, removes), (nm, nm_set) in zip(colls,
+                                                                 names):
+            u: dict = {}
+            n = _named_diff(prev_d, coll, nm, nm_set, changed, upserts,
+                            removes, u)
+            upd.append(u)
+            walked += len(nm) + n
+            serialized += n
+        if update is not None:
+            update.extend(upd)
+        if new_bytes is not None:
+            for out_d, (prev_d, *_) in zip(
+                    (new_bytes.nodes, new_bytes.pods, new_bytes.running),
+                    colls):
+                out_d.update((k, _ser(v)) for k, v in prev_d.items())
+            new_bytes.apply_update(upd)
     if walk is not None:
-        walk.append(walked)
+        walk.extend((walked, serialized))
     return delta
+
+
+def _full_diff(prev_d, coll, upserts, removes, out_d) -> "tuple[int, int]":
+    """Unnamed diff of one collection: serialize and compare every new
+    record, then scan the base for removals. Returns (records examined,
+    records serialized)."""
+    new_names = set()
+    n = 0
+    for n, rec in enumerate(coll, 1):
+        new_names.add(rec.name)
+        old = prev_d.get(rec.name)
+        raw = rec.SerializeToString()
+        if out_d is not None:
+            out_d[rec.name] = raw
+        if old is None or _ser(old) != raw:
+            upserts.append(rec)
+    m = 0
+    for m, k in enumerate(prev_d, 1):
+        if k not in new_names:
+            removes.append(k)
+    return n + m, n
+
+
+def _named_diff(prev_d, coll, names, name_set, changed, upserts, removes,
+                upd) -> int:
+    """Named diff of one collection (see delta_between). Removals come
+    from one C-level pass over the base's names; additions from the
+    named ones alone when their count accounts for every addition, else
+    from a set difference. Only the touched records are serialized, in
+    message order. Fills `upd` with the base update; returns the
+    records serialized."""
+    removed = sorted(filterfalse(name_set.__contains__, prev_d))
+    removes.extend(removed)
+    upd.update(dict.fromkeys(removed))
+    named = changed & name_set
+    added = named.difference(prev_d)
+    if len(added) != len(name_set) - len(prev_d) + len(removed):
+        added = name_set.difference(prev_d)  # an unnamed addition
+    touched = named | added
+    if not touched:
+        return 0
+    n = 0
+    for i in compress(range(len(names)), map(touched.__contains__, names)):
+        rec = coll[i]
+        raw = rec.SerializeToString()
+        n += 1
+        old = prev_d.get(names[i])
+        if old is None or _ser(old) != raw:
+            upserts.append(rec)
+            upd[names[i]] = raw
+    return n
 
 
 # ---------------------------------------------------------------------------
