@@ -1,7 +1,11 @@
 """Fast-vs-parity divergence numbers (VERDICT weak #7): constraint-free
 snapshots must agree EXACTLY; contended ones must stay valid and close."""
 
-from tpusched.divergence import measure
+import json
+
+import pytest
+
+from tpusched.divergence import PRESETS, DivergenceStats, main, measure
 
 
 def test_plain_preset_same_throughput():
@@ -29,3 +33,24 @@ def test_mixed_preset_valid_and_close():
 def test_pairwise_preset_valid():
     stats = measure("pairwise", seeds=3, n_pods=40, n_nodes=12)
     assert stats.fast_violations == 0, stats.row()
+
+
+def test_main_prints_one_row_per_preset(capsys):
+    """`python -m tpusched.divergence` with no --preset reports every
+    preset, one JSON row each, with DivergenceStats.row()'s keys."""
+    main(["--seeds", "1", "--pods", "12", "--nodes", "6"])
+    rows = [json.loads(line)
+            for line in capsys.readouterr().out.splitlines()]
+    assert [r["preset"] for r in rows] == sorted(PRESETS)
+    keys = set(DivergenceStats("plain", 1).row())
+    for r in rows:
+        assert set(r) == keys
+        assert r["seeds"] == 1 and r["pods"] == 12
+        assert r["fast_violations"] == 0, r
+
+
+def test_main_incremental_needs_warm_audit(capsys):
+    with pytest.raises(SystemExit) as exc:
+        main(["--incremental"])
+    assert exc.value.code == 2
+    assert "--incremental requires --warm-audit" in capsys.readouterr().err

@@ -4,7 +4,7 @@ identical to unpipelined solves, in order, for independent snapshots."""
 import numpy as np
 
 from tpusched import Engine, EngineConfig
-from tpusched.pipeline import bench_overlap, solve_stream
+from tpusched.pipeline import solve_stream
 from tpusched.synth import make_cluster
 
 
@@ -45,17 +45,6 @@ def test_stream_with_decode_fn():
     assert len(got) == 3
     for _, res in got:
         assert (res.assignment >= -1).all()
-
-
-def test_bench_overlap_runs():
-    """Smoke: the overlap bench returns sane numbers (CPU backend, so no
-    real overlap is asserted — just the contract)."""
-    cfg = EngineConfig(mode="fast")
-    eng = Engine(cfg)
-    stats = bench_overlap(eng, [0, 1, 2], lambda s: make_cluster(
-        np.random.default_rng(800 + s), 16, 8
-    ))
-    assert stats["sequential_s"] > 0 and stats["pipelined_s"] > 0
 
 
 def test_solve_async_matches_sync():

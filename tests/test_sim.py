@@ -576,7 +576,7 @@ def test_soak_twin_with_faults_factory():
 
 @pytest.mark.slow
 def test_matrix_run_covers_scenarios():
-    """matrix_run (the bench.py --sim-scenario all surface) produces a
+    """matrix_run (the simulate.py --scenario all surface) produces a
     row per scenario with both arms' attainment + churn + hashes."""
     from tpusched.sim.driver import matrix_run
 

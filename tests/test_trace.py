@@ -334,3 +334,70 @@ def test_watchdog_trip_produces_flight_dump(thread_leak_check):
         # Let the delayed (abandoned) fetch finish so its worker exits
         # before thread_leak_check sweeps.
         time.sleep(0.1)
+
+
+def _tracez_tool():
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parents[1] / "tools" / "tracez.py"
+    spec = importlib.util.spec_from_file_location("tpusched_tracez_tool", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+def test_tracez_demo_exports_stitched_trace(tmp_path, monkeypatch,
+                                            thread_leak_check):
+    """`tools/tracez.py --demo` writes valid trace-event JSON in which
+    each request's client and server spans share its trace_id."""
+    tool = _tracez_tool()
+    out = tmp_path / "trace.json"
+    monkeypatch.setattr("sys.argv", [
+        "tracez.py", "--demo", "--clients", "1", "--cycles", "2",
+        "--out", str(out)])
+    assert tool.main() == 0
+    doc = json.loads(out.read_text())
+    events = doc["traceEvents"]
+    assert events
+    for e in events:
+        assert e["ph"] == "X" and e["dur"] >= 0 and e["ts"] > 0, e
+        assert isinstance(e["name"], str) and "tid" in e, e
+    cats: dict = {}
+    for e in events:
+        tid = e.get("args", {}).get("trace_id")
+        if tid:
+            cats.setdefault(tid, set()).add(e["cat"])
+    assert len(cats) == 2, cats  # one trace per Assign
+    for tid, seen in cats.items():
+        assert {"client", "server"} <= seen, (tid, seen)
+
+
+def test_tracez_address_exports_live_sidecar(tmp_path, monkeypatch,
+                                             thread_leak_check):
+    """`tools/tracez.py --address` pulls a live sidecar's traces over
+    Debugz and its wire records over Statusz, and exports both."""
+    tool = _tracez_tool()
+    trace.DEFAULT.clear()
+    server, port, svc = make_server("127.0.0.1:0")
+    server.start()
+    try:
+        nodes = [dict(name="n0", allocatable={"cpu": 4000.0,
+                                              "memory": float(16 << 30)})]
+        pods = [dict(name="p0", requests={"cpu": 500.0,
+                                          "memory": float(1 << 30)})]
+        with SchedulerClient(f"127.0.0.1:{port}", wire=svc.wire) as c:
+            c.assign(snapshot_to_proto(nodes, pods, []))
+        out = tmp_path / "trace.json"
+        monkeypatch.setattr("sys.argv", [
+            "tracez.py", "--address", f"127.0.0.1:{port}", "--last", "4",
+            "--out", str(out)])
+        assert tool.main() == 0
+    finally:
+        server.stop(0)
+        svc.close()
+    events = json.loads(out.read_text())["traceEvents"]
+    server_tids = {e["args"]["trace_id"] for e in events
+                   if e["cat"] == "server" and e["args"].get("trace_id")}
+    assert len(server_tids) == 1, server_tids
+    assert any(e["cat"] == "wire" for e in events), "no wire panel row"

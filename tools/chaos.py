@@ -24,8 +24,8 @@ host's per-cycle batches slice a stable pending order, and the solver
 is deterministic — so the chaos run must reproduce the fault-free
 placements exactly or the harness fails loudly. Recovery time (fault
 event -> next completed cycle) and goodput (placements/sec vs the
-fault-free run) come out in the report; bench.py's "robustness" bench
-and tests/test_faults.py's chaos smoke both drive this module.
+fault-free run) come out in the report; tests/test_faults.py's chaos
+smoke drives this module.
 
 Round 11 (ISSUE 6) adds the FLEET experiment (`run_chaos_fleet`,
 --replicas): the same twin-run discipline over an N-replica
@@ -307,8 +307,8 @@ def run_chaos(
         delta = host._delta
     finally:
         # An exception mid-run (even inside the outage window) must not
-        # leak the server/engine/channel into the caller — bench.py runs
-        # more benches after this. Cancel an unfired restart timer first
+        # leak the server/engine/channel into the caller, which may run
+        # more experiments after this. Cancel an unfired restart timer first
         # so it cannot resurrect a server nobody stops (stop() is
         # idempotent, so a FIRED timer's server is simply stopped here).
         for t in timers:
@@ -574,7 +574,7 @@ def run_chaos_fleet(
     shapes (later arms hit the in-process compile caches); without a
     warmup, a cold fault-free twin can lose to a warm chaos arm and
     invert the goodput fraction. Callers comparing goodput across
-    replica counts set it on their FIRST run (bench.py does).
+    replica counts set it on their FIRST run.
 
     prewarm (PR 18): boot every replica with explicit synthetic
     buckets + the shape-class registry prewarm, and make the compile-

@@ -5,7 +5,7 @@ Chains, in order:
 
   regen    tools/regen_pb2.py --check   (generated pb2 in sync with
            the descriptor splice recipe)
-  lint     tools/lint.py over tpusched/ tools/ bench.py tests/
+  lint     tools/lint.py over tpusched/ tools/ tests/
            (the tpuschedlint invariant suite, empty baseline)
   lockgraph  tools/lint.py --check-hierarchy: the checked-in
            tools/lock_hierarchy.json matches a fresh regeneration
@@ -66,8 +66,8 @@ import sys
 from pathlib import Path
 
 REPO_ROOT = Path(__file__).resolve().parents[1]
-LINT_PATHS = ("tpusched", "tools", "bench.py", "tests")
-SYNTAX_ROOTS = ("tpusched", "tools", "tests", "bench.py")
+LINT_PATHS = ("tpusched", "tools", "tests")
+SYNTAX_ROOTS = ("tpusched", "tools", "tests")
 MYPY_TARGETS = ("tpusched/config.py", "tpusched/qos.py",
                 "tpusched/metrics.py", "tpusched/ledger.py",
                 "tpusched/trace.py", "tpusched/wire.py",
@@ -126,9 +126,7 @@ def _py_files() -> "list[Path]":
     out = []
     for root in SYNTAX_ROOTS:
         p = REPO_ROOT / root
-        if p.is_file():
-            out.append(p)
-        elif p.is_dir():
+        if p.is_dir():
             out.extend(sorted(p.rglob("*.py")))
     return out
 

@@ -5,7 +5,7 @@ Runs the AST rule suite in tpusched/lint/ over the given paths and
 fails on any finding not covered by the checked-in baseline. The
 tier-1 gate (tests/test_lint.py::test_tree_is_clean) runs exactly:
 
-  python tools/lint.py tpusched tools bench.py tests
+  python tools/lint.py tpusched tools tests
 
 Suppress a legitimate exception per line, reason mandatory:
 
@@ -13,7 +13,7 @@ Suppress a legitimate exception per line, reason mandatory:
 
 Baseline workflow (for landing a NEW rule against an old tree):
 
-  python tools/lint.py --write-baseline tpusched tools bench.py tests
+  python tools/lint.py --write-baseline tpusched tools tests
   ... fix findings, shrinking tools/lint_baseline.json to [] ...
 
 The baseline at HEAD is kept EMPTY; entries are grandfathered debt,
@@ -61,7 +61,7 @@ from tpusched.lint.engine import apply_baseline, parse_suppressions  # noqa: E40
 DEFAULT_BASELINE = REPO_ROOT / "tools" / "lint_baseline.json"
 DEFAULT_HIERARCHY = REPO_ROOT / "tools" / "lock_hierarchy.json"
 DEFAULT_LEDGER = REPO_ROOT / "tools" / "reduction_ledger.json"
-DEFAULT_PATHS = ("tpusched", "tools", "bench.py", "tests")
+DEFAULT_PATHS = ("tpusched", "tools", "tests")
 
 
 def _program() -> "interproc.Program":
@@ -176,8 +176,8 @@ def cmd_check_ledger() -> int:
                       f"{rec['path']}:{rec['line']} ({rec['op']})",
                       file=sys.stderr)
         ok = False
-    # Trend metric for benchdiff (lower is better: hazards shrink as
-    # conversions land).
+    # Trend metric (lower is better: hazards shrink as conversions
+    # land).
     print(json.dumps({"metric": "kernelflow_findings_total",
                       "value": float(t["findings"]), "unit": "count",
                       "direction": "lower"}))

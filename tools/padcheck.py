@@ -45,10 +45,10 @@ Run it:
                                         # (needs >= 2 jax devices)
 
 Exits non-zero on any divergence-in-exact, uncovered site, mesh
-divergence, or self-test miss. Emits bench-style metric lines
+divergence, or self-test miss. Emits JSON metric lines
 (padcheck_sites_total / padcheck_divergences_total /
-padcheck_mesh_divergences_total, all lower-better) so benchdiff
-trend-tracks analyzer coverage next to perf.
+padcheck_mesh_divergences_total, all lower-better) so analyzer
+coverage can be tracked across changes.
 """
 
 from __future__ import annotations
@@ -316,32 +316,7 @@ def _harnesses() -> List[Harness]:
 
     out.append(Harness("explain_probe", ("explain_probe",), run_explain))
 
-    # 7. The profiling-only node-major preemption tableau (kept covered
-    # so its ledger sites are validated, not just suppressed).
-    def run_tableau_nv(mult: int) -> Dict[str, np.ndarray]:
-        import jax
-        import jax.numpy as jnp
-        from tpusched.config import EngineConfig as EC
-        cfg = EC(mode="fast", preemption=True)
-        snap, _meta, P, _M = _build("preempt", mult, cfg)
-        snap = jax.tree.map(jnp.asarray, snap)
-        ctx = kpreempt.precompute_nv(cfg, snap, 8)
-        Mb = snap.running.valid.shape[0]
-        C, N = 4, 8
-        elig, wcost, wviol, fits, node_viol, node_cost = \
-            kpreempt._tableau_nv(
-                cfg, snap, ctx, jnp.full((C,), 1e9, jnp.float32),
-                snap.pods.requests[:C], snap.nodes.used,
-                jnp.zeros(Mb, bool),
-            )
-        return {"node_viol": np.asarray(node_viol)[:, :N],
-                "node_cost": np.asarray(node_cost)[:, :N],
-                "fits": np.asarray(fits)[:, :N]}
-
-    out.append(Harness("tableau_nv", ("_tableau_nv", "precompute_nv"),
-                       run_tableau_nv))
-
-    # 8. The ring/blockwise pairwise counting vs the dense path, on a
+    # 7. The ring/blockwise pairwise counting vs the dense path, on a
     # single-device 'p' ring (the layout the ring path exists for).
     def run_ring(mult: int) -> Dict[str, np.ndarray]:
         import jax
@@ -367,7 +342,7 @@ def _harnesses() -> List[Harness]:
     out.append(Harness("ring_counts", ("ring_sig_counts", "sig_counts"),
                        run_ring))
 
-    # 9. The device-resident pending queue (ISSUE 20): full ranking,
+    # 8. The device-resident pending queue (ISSUE 20): full ranking,
     # the top-kb window slice, and the numpy host oracle. Padding IS
     # the table's natural regime — a bigger pow2 capacity means more
     # invalid slots — so the pad multiple grows Q while the P real

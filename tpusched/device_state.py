@@ -1242,8 +1242,7 @@ class DeviceQueue:
         self._dirty: set[int] = set()
         self._epoch: float | None = None    # first-submit time rebase
         self._seq = 0                       # arrival sequence stamp
-        # Profiling counters (tools/prof_components.py --queue and the
-        # ingest bench read these).
+        # Profiling counters, reported by stats().
         self.scatters = 0
         self.scatter_rows_total = 0
         self.windows = 0

@@ -123,7 +123,7 @@ def measure(
     """Run fast and parity over `seeds` random snapshots of a preset and
     accumulate agreement statistics. Every fast assignment is also run
     through the independent validity audit. `engines` = (fast, parity)
-    to reuse jit caches across presets (bench.py does)."""
+    to reuse jit caches across presets."""
     kw = PRESETS[preset]
     if engines is not None:
         fast, parity = engines

@@ -1105,7 +1105,7 @@ class HostScheduler:
 
 
 # ---------------------------------------------------------------------------
-# E2E benchmark entry (BASELINE.json:"configs"[0]; used by bench.py).
+# Synthetic BASELINE.json:"configs"[0] cluster (tests, tools/chaos.py).
 # ---------------------------------------------------------------------------
 
 
@@ -1146,66 +1146,3 @@ def synthetic_buckets(n_pods: int, n_nodes: int) -> Buckets:
     content-derived buckets would grow as pods bind and recompile
     mid-run."""
     return Buckets.fit(n_pods, n_nodes, n_running=n_pods)
-
-
-def run_e2e_benchmark(n_pods: int = 100, n_nodes: int = 10, iters: int = 10,
-                      use_grpc: bool = True, prewarm: bool = False):
-    """Full-boundary E2E: fake API server -> host shim -> gRPC sidecar
-    -> engine -> binds. Returns bench.py-style percentile stats of the
-    complete cycle latency plus placements/sec. prewarm=True boots the
-    sidecar with pinned synthetic_buckets and the full shape-class
-    registry traced (and reports the boot cost as cold_start_s /
-    prewarm_s), so the "+1 warmup" iteration pays no compile."""
-    from tpusched.rpc.client import SchedulerClient  # tpl: disable=TPL001(grpc transport is optional; the in-process host must import without grpc)
-    from tpusched.rpc.server import make_server  # tpl: disable=TPL001(grpc transport is optional; the in-process host must import without grpc)
-
-    cfg = EngineConfig(mode="fast")
-    server = client = shared_engine = svc = None
-    boot = dict(cold_start_s=0.0, prewarm_s=0.0)
-    if use_grpc:
-        t_boot = time.perf_counter()
-        server, port, svc = make_server(
-            "127.0.0.1:0", config=cfg,
-            buckets=synthetic_buckets(n_pods, n_nodes) if prewarm else None,
-            prewarm=prewarm)
-        server.start()
-        svc.wait_prewarmed()
-        boot["cold_start_s"] = round(time.perf_counter() - t_boot, 6)
-        boot["prewarm_s"] = svc.prewarm_s
-        client = SchedulerClient(f"127.0.0.1:{port}")
-    else:
-        shared_engine = Engine(cfg)  # one jit cache across iterations
-    times, placed_total = [], 0
-    try:
-        for it in range(iters + 1):  # +1 warmup (compile)
-            api = FakeApiServer()
-            rng = np.random.default_rng(1000 + it)
-            build_synthetic_cluster(api, rng, n_pods, n_nodes)
-            host = HostScheduler(api, cfg, client=client, engine=shared_engine)
-            t0 = time.perf_counter()
-            host.run_until_idle()
-            dt = time.perf_counter() - t0
-            placed = sum(c.placed for c in host.cycles)
-            if it > 0:  # skip compile iteration
-                times.append(dt)
-                placed_total += placed
-    finally:
-        if client is not None:
-            client.close()
-        if server is not None:
-            server.stop(0)
-        if svc is not None:
-            svc.close()
-        if shared_engine is not None:
-            shared_engine.close()
-    times = np.asarray(times)
-    return dict(
-        p50=float(np.percentile(times, 50)),
-        p90=float(np.percentile(times, 90)),
-        p99=float(np.percentile(times, 99)),
-        max=float(times.max()),
-        mean=float(times.mean()),
-        iters=len(times),
-        placements_per_sec=round(placed_total / times.sum(), 1),
-        **boot,
-    )

@@ -166,69 +166,6 @@ def precompute_nv(cfg: EngineConfig, snap: ClusterSnapshot,
     )
 
 
-def _tableau_nv(cfg: EngineConfig, snap: ClusterSnapshot,
-                ctx: PreemptCtxNV, p_prio, p_req, used, evicted):
-    """All C bidders' victim-prefix tableaus at once on the node-major
-    table: [C, N, V] arrays, V-length prefix sums, PDB counts as one
-    triangular [V, V] contraction. Ranking semantics identical to
-    _tableau (lexicographic (violations, cost) min over feasible
-    prefixes per node). Returns (elig, wcost, wviol, fits,
-    node_viol [C, N], node_cost [C, N]) with [C, N, V] leading four.
-
-    Round 6: RETAINED FOR PROFILING/REFERENCE ONLY
-    (tools/prof_components.py slopes it) — preempt_auction no longer
-    materializes it; see its docstring for the [N, V]-table + [C, V]
-    validation restructure that replaced the ~0.5 GB/round of f32
-    cumsums this form costs at 10k x 5k."""
-    nodes = snap.nodes
-    N, V = ctx.vvalid.shape
-    M = evicted.shape[0]
-    ev_nv = evicted[jnp.clip(ctx.vidx, 0, M - 1)] & ctx.vvalid
-    base_elig = ctx.vvalid & ~ev_nv                          # [N, V]
-    elig = base_elig[None] & (
-        ctx.vprio[None] + cfg.qos.preemption_margin
-        < p_prio[:, None, None]
-    )                                                        # [C, N, V]
-    gr = jnp.where(elig[..., None], ctx.vreq[None], 0.0)
-    wreq = jnp.cumsum(gr, axis=2)                            # [C, N, V, R]  # tpl: disable=TPL201(_tableau_nv is retained for profiling/reference only — prof_components slopes it; no product path calls it)
-    fits = elig & jnp.all(
-        used[None, :, None, :] - wreq + p_req[:, None, None, :]
-        <= nodes.allocatable[None, :, None, :],
-        axis=-1,
-    )
-    wcost = jnp.cumsum(jnp.where(elig, ctx.vcost[None], 0.0), axis=2)
-    GP = snap.pdb_allowed.shape[0]
-    if GP:
-        run_pdb = snap.running.pdb_group
-        consumed = jnp.zeros(GP, jnp.float32).at[
-            jnp.clip(run_pdb, 0, None)
-        ].add(
-            (evicted & (run_pdb >= 0) & snap.running.valid).astype(
-                jnp.float32
-            )
-        )
-        remaining = snap.pdb_allowed - consumed              # [GP]
-        has_pdb = ctx.vpdb >= 0                              # [N, V]
-        tri = (
-            jnp.arange(V)[:, None] >= jnp.arange(V)[None, :]
-        )                                                    # [V(v), V(w)]
-        same_g = (
-            (ctx.vpdb[:, :, None] == ctx.vpdb[:, None, :])
-            & has_pdb[:, :, None] & tri[None]
-        ).astype(jnp.float32)                                # [N, V, V]
-        eligp = (elig & has_pdb[None]).astype(jnp.float32)
-        wcnt = jnp.einsum("nvw,cnw->cnv", same_g, eligp)
-        rem_nv = remaining[jnp.clip(ctx.vpdb, 0, None)]      # [N, V]
-        viol = elig & has_pdb[None] & (wcnt > rem_nv[None])
-    else:
-        viol = jnp.zeros_like(elig)
-    wviol = jnp.cumsum(viol.astype(jnp.float32), axis=2)
-    node_viol = jnp.min(jnp.where(fits, wviol, jnp.inf), axis=2)
-    fits_v = fits & (wviol == node_viol[..., None])
-    node_cost = jnp.min(jnp.where(fits_v, wcost, jnp.inf), axis=2)
-    return elig, wcost, wviol, fits, node_viol, node_cost
-
-
 def _tableau(cfg: EngineConfig, snap: ClusterSnapshot, ctx: PreemptCtx,
              p_prio, p_req, used, evicted):
     """One preemptor's victim-prefix tableau: everything preempt_step

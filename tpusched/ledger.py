@@ -471,8 +471,7 @@ def _r(v: float) -> "float | None":
 # Process defaults: the engine's jit wrappers feed COMPILES; host /
 # pipeline / sim emitters fall back to DEFAULT unless handed their own
 # ledger (the sidecar builds one per service so its anomalies render in
-# its own Metrics rpc). `set_enabled(False)` is the global off switch —
-# bench.py's ledger-off arm measures exactly this path.
+# its own Metrics rpc). `set_enabled(False)` is the global off switch.
 COMPILES = CompileWatcher()
 DEFAULT = CycleLedger()
 

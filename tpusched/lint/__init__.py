@@ -9,7 +9,6 @@ tools/README.md "Static analysis"):
     TPL003  known-cost calls lexically under a lock
     TPL004  inline [0,1] clamps bypassing config.clamp01
     TPL005  threading.Thread without a tpusched- name
-    TPL006  bench.py metric emitted without a resolvable direction
     TPL007  next(reversed(...)) dict-order-dependent selection
     TPL008  sorted() on round/seq-shaped keys without a numeric key
     TPL009  trace.DEFAULT/explain.DEFAULT outside the fallback idiom
@@ -50,7 +49,7 @@ The static lock order is checked in as tools/lock_hierarchy.json
 ``tools/check.py`` lockgraph failure) and validated at runtime by the
 lock-order witness tier-1 installs via tests/conftest.py.
 
-Run via ``python tools/lint.py tpusched tools bench.py tests`` (the
+Run via ``python tools/lint.py tpusched tools tests`` (the
 tier-1 gate, tests/test_lint.py::test_tree_is_clean) or through
 ``tools/check.py``. Per-line suppressions:
 

@@ -12,7 +12,7 @@ Design constraints (round 15, ISSUE 10):
   suppression is itself a finding (TPL000) — the escape hatch is part
   of the documented invariant surface, not a way around it.
 * The baseline exists for grandfathering a rule in; the repo keeps it
-  empty (acceptance: ``tools/lint.py tpusched tools bench.py`` exits 0
+  empty (acceptance: ``tools/lint.py tpusched tools tests`` exits 0
   with ``tools/lint_baseline.json == []``).
 """
 
@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import ast
 import dataclasses
-import importlib.util
 import io
 import json
 import re
@@ -58,23 +57,19 @@ class Finding:
 class LintContext:
     """Cross-file project knowledge shared by all rules over one run.
 
-    ``root`` anchors relative paths; ``closeable_classes`` (TPL010) and
-    ``benchdiff`` (TPL006) are computed lazily so linting a single
-    fixture snippet never scans the tree, and both are injectable for
-    rule unit tests.
+    ``root`` anchors relative paths; ``closeable_classes`` (TPL010) is
+    computed lazily so linting a single fixture snippet never scans the
+    tree, and is injectable for rule unit tests.
     """
 
     def __init__(
         self,
         root: "Path | None" = None,
         closeable_classes: "set[str] | None" = None,
-        benchdiff: Any = None,
         program_sources: "dict[str, str] | None" = None,
     ) -> None:
         self.root = Path(root) if root is not None else _default_root()
         self._closeable = closeable_classes
-        self._benchdiff = benchdiff
-        self._benchdiff_loaded = benchdiff is not None
         # Whole-program index (round 19, ISSUE 14): `program_sources`
         # injects an explicit {relpath: src} universe for multi-file
         # rule tests; None scans the real product tree lazily.
@@ -142,31 +137,10 @@ class LintContext:
         srcs[relpath] = src
         return kernelflow.KernelProgram(srcs)
 
-    @property
-    def benchdiff(self) -> Any:
-        """tools/benchdiff.py as a module (direction-inference source
-        of truth for TPL006), or None when the repo doesn't carry it."""
-        if not self._benchdiff_loaded:
-            self._benchdiff_loaded = True
-            self._benchdiff = _load_benchdiff(self.root)
-        return self._benchdiff
-
 
 def _default_root() -> Path:
     # tpusched/lint/engine.py -> tpusched/lint -> tpusched -> repo root
     return Path(__file__).resolve().parents[2]
-
-
-def _load_benchdiff(root: Path) -> Any:
-    path = root / "tools" / "benchdiff.py"
-    if not path.exists():
-        return None
-    spec = importlib.util.spec_from_file_location(
-        "tpusched_lint_benchdiff", path
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def scan_closeable_classes(pkg_dir: Path) -> "set[str]":

@@ -317,17 +317,14 @@ class _ModuleInfo:
 
 
 def scan_product_sources(root: Path) -> dict[str, str]:
-    """The whole-program file set: tpusched/**, tools/*, bench.py —
-    the same non-test product surface the per-file rules gate."""
+    """The whole-program file set: tpusched/** and tools/* — the same
+    non-test product surface the per-file rules gate."""
     out: dict[str, str] = {}
     for sub in ("tpusched", "tools"):
         base = root / sub
         if base.is_dir():
             for p in sorted(base.rglob("*.py")):
                 out[p.relative_to(root).as_posix()] = p.read_text()
-    bench = root / "bench.py"
-    if bench.is_file():
-        out["bench.py"] = bench.read_text()
     return out
 
 

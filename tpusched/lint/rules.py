@@ -9,7 +9,7 @@ per-line suppression whose mandatory reason documents WHY the line is
 exempt — the suppression text is the living review checklist.
 
 Applicability is path-based (repo-relative POSIX paths): most rules
-cover product code (tpusched/, tools/, bench.py) and skip tests;
+cover product code (tpusched/, tools/) and skip tests;
 TPL010 covers ONLY test files. Passing any mix of paths to the engine
 is safe — each rule selects its own territory.
 """
@@ -99,12 +99,10 @@ def is_test_path(relpath: str) -> bool:
 
 
 def product_path(relpath: str) -> bool:
-    """tpusched/, tools/, or bench.py — the non-test gate surface."""
+    """tpusched/ or tools/ — the non-test gate surface."""
     if is_test_path(relpath):
         return False
-    return (relpath.startswith("tpusched/")
-            or relpath.startswith("tools/")
-            or relpath.rsplit("/", 1)[-1] == "bench.py")
+    return relpath.startswith("tpusched/") or relpath.startswith("tools/")
 
 
 class Rule:
@@ -460,115 +458,6 @@ class UnnamedThread(Rule):
             return ('f-string thread name must start with a literal '
                     '"tpusched-" prefix')
         return None  # dynamic expression: runtime backstop applies
-
-
-# ---------------------------------------------------------------------------
-# TPL006 — bench metric direction resolution.
-# ---------------------------------------------------------------------------
-
-class BenchMetricDirection(Rule):
-    """Every JSON metric line bench.py prints must resolve to a
-    better-direction under tools/benchdiff.py's rules — explicit
-    `"direction"` key, lower-better unit, or a name pattern — or
-    benchdiff silently trends it higher-better and a regression reads
-    as an improvement. Checked at the dict-literal level (the shape
-    benchdiff parses); a dynamic metric name requires the explicit
-    direction key because no pattern can be proven against it.
-    """
-
-    rule_id = "TPL006"
-    title = "bench metric without a resolvable direction"
-    incident = ("PR 8: the *_frac/*_churn families trended as "
-                "higher-better until benchdiff grew explicit "
-                "direction annotations")
-
-    def applies(self, relpath: str) -> bool:
-        return relpath.rsplit("/", 1)[-1] == "bench.py"
-
-    def check(self, tree: ast.Module, src: str, relpath: str,
-              ctx: "LintContext",
-              parents: "dict[ast.AST, ast.AST]") -> "list[Finding]":
-        bd = ctx.benchdiff
-        if bd is None:  # no benchdiff in this tree: nothing to resolve against
-            return []
-        findings = []
-        for node in ast.walk(tree):
-            if not isinstance(node, ast.Dict):
-                continue
-            fields = self._fields(node)
-            if fields is None:
-                continue
-            name_node, unit, direction_kw = fields
-            if direction_kw is not None:
-                if (isinstance(direction_kw, ast.Constant)
-                        and direction_kw.value not in ("higher", "lower")):
-                    findings.append(self.finding(
-                        relpath, node,
-                        f"direction {direction_kw.value!r} is not "
-                        "'higher'|'lower'",
-                    ))
-                continue
-            name = self._static_name(name_node)
-            if name is None:
-                if unit is not None and unit in bd._LOWER_BETTER_UNITS:
-                    continue
-                findings.append(self.finding(
-                    relpath, node,
-                    "dynamic metric name without an explicit "
-                    '"direction" key — benchdiff cannot infer its '
-                    "better-direction",
-                ))
-                continue
-            if unit is not None and unit in bd._LOWER_BETTER_UNITS:
-                continue
-            if (bd._HIGHER_BETTER_NAME.search(name)
-                    or bd._LOWER_BETTER_NAME.search(name)):
-                continue
-            findings.append(self.finding(
-                relpath, node,
-                f"metric {name!r} (unit {unit!r}) resolves to no "
-                "benchdiff direction — add \"direction\": "
-                "\"higher\"|\"lower\"",
-            ))
-        return findings
-
-    @staticmethod
-    def _fields(node: ast.Dict) -> (
-            "tuple[ast.expr | None, str | None, ast.expr | None] | None"):
-        """(metric value node, static unit or None, direction value
-        node or None) for dicts carrying a "metric" key; None for
-        other dicts."""
-        name_node = unit = direction = None
-        seen_metric = False
-        for k, v in zip(node.keys, node.values):
-            if not (isinstance(k, ast.Constant) and isinstance(k.value, str)):
-                continue
-            if k.value == "metric":
-                seen_metric, name_node = True, v
-            elif k.value == "unit":
-                if isinstance(v, ast.Constant) and isinstance(v.value, str):
-                    unit = v.value
-            elif k.value == "direction":
-                direction = v
-        if not seen_metric:
-            return None
-        return name_node, unit, direction
-
-    @staticmethod
-    def _static_name(node: "ast.AST | None") -> "str | None":
-        """Literal or f-string metric name, formatted values rendered
-        as '0' so shape suffixes still pattern-match."""
-        if isinstance(node, ast.Constant) and isinstance(node.value, str):
-            return node.value
-        if isinstance(node, ast.JoinedStr):
-            parts = []
-            for v in node.values:
-                if isinstance(v, ast.Constant):
-                    parts.append(str(v.value))
-                else:
-                    parts.append("0")
-            return "".join(parts)
-        return None
 
 
 # ---------------------------------------------------------------------------
@@ -984,7 +873,7 @@ class PerCallJitConstruction(Rule):
     hangs off the jit object, so every call retraces and recompiles —
     the exact compile anomalies ledger.COMPILES attributes
     (`scheduler_cycle_anomalies_total{cause="compile"}`, ROADMAP item
-    4). tpusched/ only: bench/profiler scripts construct jits per run
+    4). tpusched/ only: tool scripts construct jits per run
     deliberately.
     """
 
@@ -1110,7 +999,6 @@ RULES = (
     WorkUnderLock,
     InlineUnitClamp,
     UnnamedThread,
-    BenchMetricDirection,
     DictOrderSelection,
     StringSortedRounds,
     CollectorDefaultDiscipline,

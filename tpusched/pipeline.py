@@ -27,8 +27,6 @@ from __future__ import annotations
 import time
 from typing import Any, Callable, Iterable, Iterator
 
-import numpy as np
-
 from tpusched import ledger as ledgering
 from tpusched.engine import Engine, SolveResult
 from tpusched.snapshot import ClusterSnapshot
@@ -163,32 +161,3 @@ def warm_cycle_stream(
         in_flight = (stats, pending, ctx)
     if in_flight is not None:
         yield _join(in_flight)
-
-
-def bench_overlap(
-    engine: Engine,
-    batches: list[Any],
-    decode: Callable[[Any], tuple[ClusterSnapshot, Any]],
-) -> dict:
-    """Measure sequential vs pipelined wall-clock over the same batch
-    list (first batch compiles and is excluded via a warmup pass).
-    Returns {sequential_s, pipelined_s, speedup}."""
-    # Warmup/compile on the first batch.
-    snap, _ = decode(batches[0])
-    np.asarray(engine._solve_packed_jit(engine.put(snap)))
-
-    t0 = time.perf_counter()
-    for item in batches:
-        snap, _ = decode(item)
-        np.asarray(engine._solve_packed_jit(engine.put(snap)))
-    sequential = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    for _ in solve_stream(engine, batches, decode):
-        pass
-    pipelined = time.perf_counter() - t0
-    return dict(
-        sequential_s=sequential,
-        pipelined_s=pipelined,
-        speedup=sequential / pipelined if pipelined > 0 else float("inf"),
-    )

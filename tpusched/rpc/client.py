@@ -366,8 +366,8 @@ class SchedulerClient:
         if ledger is not None:
             # The wire ledger's serialize component: one timed pass
             # over the request (gRPC's own serializer hits protobuf's
-            # warmed path right after). Only paid while ledgering —
-            # the OFF arm of bench.py's wire overhead check skips it.
+            # warmed path right after). Only paid while ledgering; a
+            # disabled wire ledger skips it.
             t_ser = time.perf_counter()
             bytes_up = len(request.SerializeToString())
             self.tracer.record(

@@ -114,6 +114,7 @@ Round 9 (ISSUE 4) makes the whole pipeline OBSERVABLE:
 
 from __future__ import annotations
 
+import argparse
 import hashlib
 import json
 import logging
@@ -140,7 +141,7 @@ from tpusched import trace as tracing
 from tpusched import wire as wiring
 from tpusched.faults import NO_FAULTS
 from tpusched.mesh import make_mesh
-from tpusched.config import Buckets, EngineConfig
+from tpusched.config import Buckets, EngineConfig, load_config
 from tpusched.device_state import DeviceQueue, DeviceSnapshot
 from tpusched.ingest import IngestGate
 from tpusched.replicate import ReplicationLog
@@ -2534,9 +2535,9 @@ def serve(address: str = "127.0.0.1:50051", config: EngineConfig | None = None,
         svc.close()
 
 
-if __name__ == "__main__":
-    import argparse
-
+def main(argv: "list[str] | None" = None) -> None:
+    """Command line of ``python -m tpusched.rpc.server``: parse the
+    flags and run :func:`serve` until terminated."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--address", default="127.0.0.1:50051")
     ap.add_argument("--config", default=None, help="EngineConfig YAML path")
@@ -2560,19 +2561,24 @@ if __name__ == "__main__":
                     help="trace the full shape-class registry at boot "
                          "(PR 18: zero request-path compiles afterward; "
                          "needs --buckets)")
-    args = ap.parse_args()
-    cfg = None
-    if args.config:
-        from tpusched.config import load_config
-
-        cfg = load_config(args.config)
+    args = ap.parse_args(argv)
     bk = None
     if args.buckets:
-        dims = [int(x) for x in args.buckets.lower().split("x")]
+        try:
+            dims = [int(x) for x in args.buckets.lower().split("x")]
+        except ValueError:
+            dims = []
         if len(dims) not in (2, 3):
             ap.error("--buckets wants PODSxNODES or PODSxNODESxRUNNING")
         bk = Buckets.fit(*dims)
+    if args.prewarm and bk is None:
+        ap.error("--prewarm needs --buckets")
+    cfg = load_config(args.config) if args.config else None
     serve(args.address, cfg, audit_path=args.audit,
           watchdog_s=args.watchdog_s, explain=args.explain,
           ledger_jsonl=args.ledger_jsonl, buckets=bk,
           prewarm=args.prewarm)
+
+
+if __name__ == "__main__":
+    main()

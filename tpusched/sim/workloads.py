@@ -334,8 +334,8 @@ def generate(sc: Scenario, seed: int) -> SimSetup:
 #
 # The Borg/Azure-shaped presets live in tpusched/sim/generators.py and
 # are merged into this registry at the bottom of this module; matrix
-# consumers (bench.py --sim-scenario all, tools/simulate.py --scenario
-# all) iterate MATRIX_SCENARIOS.
+# consumers (tools/simulate.py --scenario all) iterate
+# MATRIX_SCENARIOS.
 # ---------------------------------------------------------------------------
 
 
@@ -416,9 +416,9 @@ SCENARIOS: dict[str, Scenario] = {
 # module), and either entry module leaves the registry complete.
 import tpusched.sim.generators  # noqa: E402,F401  (side effect: merge)
 
-# The bench.py --sim / simulate.py matrix: every scenario cheap enough
-# to twin-run in one bench invocation (the long-horizon soak is
-# deliberately excluded — run it alone, or via its bounded smoke).
+# The simulate.py matrix: every scenario cheap enough to twin-run in
+# one invocation (the long-horizon soak is deliberately excluded — run
+# it alone, or via its bounded smoke).
 MATRIX_SCENARIOS: tuple = (
     "steady_state",
     "burst",

@@ -418,8 +418,7 @@ class StormDetector:
 # Process default: clients, the sidecar, and the event streams
 # (device_state rebuilds, faults, kube reconnects) all share this
 # collector unless handed their own, so an in-process client+server run
-# yields ONE stitched ring. `set_enabled(False)` is the global off
-# switch (bench.py --trace=off measures the disabled path).
+# yields ONE stitched ring. `set_enabled(False)` is the global off switch.
 DEFAULT = TraceCollector()
 
 

@@ -669,8 +669,7 @@ def _ms(v: float) -> "float | None":
 # Process default: clients fall back here unless handed the sidecar's
 # own ledger (the server builds one per service so its wire panel and
 # anomaly counters render in its own Statusz/Metrics rpcs).
-# `set_enabled(False)` is the global off switch — bench.py's
-# wire-ledger-off arm measures exactly this path.
+# `set_enabled(False)` is the global off switch.
 DEFAULT = WireLedger()
 
 
