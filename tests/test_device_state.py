@@ -425,8 +425,8 @@ def _random_delta(rng, k, nodes, pods, running, base):
     return delta
 
 
-DERIVED = ("_run_by_node", "_run_node_name", "_pod_group_name",
-           "_run_pdb_key", "_group_members", "_pdb_members")
+DERIVED = ("_run_node_name", "_pod_group_name", "_run_pdb_key",
+           "_group_members", "_pdb_members")
 
 
 def _mirror(ds):
@@ -462,6 +462,7 @@ def test_churn_upkeep_equals_rebuild(seed):
         ref.full_load(nodes.values(), pods.values(), running.values())
         for attr in DERIVED:
             assert getattr(ds, attr) == getattr(ref, attr), (k, attr)
+        assert np.array_equal(ds._run_counted, ref._run_counted), k
         assert ds._state.node_index == ref._state.node_index
         assert ds.meta == ref.meta
         _assert_trees_equal(_mirror(ds), _mirror(ref))
@@ -494,5 +495,152 @@ def test_order_upkeep_scales_with_churn_not_cluster():
     assert stats.churn_records <= stats.order_visited
     assert stats.order_visited < 2 * stats.churn_records * np.log2(n_run)
     assert ds._run_order == sorted(ds._running)
-    assert ds._run_by_node["n005"] == sorted(
-        nm for nm, r in ds._running.items() if r["node"] == "n005")
+    ref = DeviceSnapshot(EngineConfig(), ds.meta.buckets)
+    ref.full_load(ds._nodes.values(), ds._pods.values(),
+                  ds._running.values())
+    i = ds._state.node_index["n005"]
+    assert np.array_equal(ds._state.nodes_np.used[i],
+                          ref._state.nodes_np.used[i])
+    # The re-sum folds every member of each dirtied node, not the churn.
+    dirty = {f"n{i % 64:03d}" for i in range(0, n_run, 1000)} | {"n005"}
+    assert stats.used_nodes == len(dirty)
+    assert stats.used_rows == sum(r["node"] in dirty
+                                  for r in ds._running.values())
+
+
+def _sum_order_cluster(rng):
+    """A few nodes with their own `used`, each holding many running pods
+    whose float32 sums depend on the order of the additions: fractional
+    cpu (0.1, 1/3, 1e-3) beside large memory values. About a tenth of
+    the members do not count into `used`."""
+    nodes = {f"n{i:02d}": dict(
+        name=f"n{i:02d}", allocatable={"cpu": 1e6, "memory": 1e13},
+        labels={"zone": "ab"[i % 2]},
+        used={"cpu": float(rng.choice([0.0, 0.7, 1 / 7])),
+              "memory": float(rng.integers(1, 1 << 20))})
+        for i in range(0, 12, 2)}
+    running = {}
+    for j in range(120):
+        nm = f"r{j:03d}"
+        running[nm] = _sum_order_member(rng, nm, sorted(nodes)[j % 3])
+    pods = {f"p{j}": dict(name=f"p{j}", requests={"cpu": 1.0})
+            for j in range(4)}
+    return nodes, pods, running
+
+
+def _sum_order_member(rng, name, node):
+    return dict(name=name, node=node,
+                requests={"cpu": float(rng.choice([0.1, 1 / 3, 1e-3])),
+                          "memory": float((3 << 30) + rng.integers(1, 999)
+                                          * 7.3)},
+                count_into_used=bool(rng.random() < 0.9))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_used_resum_is_bit_identical_to_rebuild(seed):
+    """The churned nodes' `used` rows, re-summed on the delta path, are
+    bit-identical to a from-scratch load of the same records after
+    binds, completions, moves between nodes, count_into_used flips,
+    node inserts (which remap every running row's node) and node
+    removals, with removals that leave pad rows — on requests whose
+    float32 sum differs when the additions are reordered."""
+    rng = np.random.default_rng(seed)
+    nodes, pods, running = _sum_order_cluster(rng)
+    ds = DeviceSnapshot(EngineConfig(), Buckets.fit(8, 16, 256))
+    ds.full_load(nodes.values(), pods.values(), running.values())
+    # The data tells the orders apart: a node's members summed in
+    # reverse order give another float32 row than the build's.
+    st = ds._state
+    rows = np.flatnonzero(ds._run_counted & (st.run_np.node_idx == 0))
+    fwd = np.zeros(len(ds.config.resources), np.float32)
+    rev = fwd.copy()
+    for k in rows:
+        fwd += st.run_np.requests[k]
+    for k in rows[::-1]:
+        rev += st.run_np.requests[k]
+    assert not np.array_equal(fwd, rev)
+    pads = 0
+    for k in range(8):
+        delta = dict(upsert_nodes=[], remove_nodes=[], upsert_running=[],
+                     remove_running=[])
+        live = sorted(nodes)
+        if k % 3 == 0:                       # insert: node rows shift
+            nm = f"n{int(rng.integers(0, 10)):02d}x{k}"
+            nodes[nm] = dict(name=nm, allocatable={"cpu": 1e6},
+                             labels={"zone": "a"}, used={"cpu": 0.3})
+            delta["upsert_nodes"].append(nodes[nm])
+            live = sorted(nodes)
+        elif k % 3 == 1:                     # remove an inserted node
+            extra = [n for n in nodes if "x" in n]
+            if extra:
+                gone = extra[0]
+                del nodes[gone]
+                delta["remove_nodes"].append(gone)
+                live = sorted(nodes)
+                for r in [r for r in running.values()
+                          if r["node"] == gone]:
+                    running[r["name"]] = dict(r, node=live[0])
+                    delta["upsert_running"].append(running[r["name"]])
+        moved = {r["name"] for r in delta["upsert_running"]}
+        for nm in sorted(running):
+            if nm in moved:
+                continue
+            u = rng.random()
+            if u < 0.12:                     # complete
+                del running[nm]
+                delta["remove_running"].append(nm)
+            elif u < 0.22:                   # move
+                running[nm] = dict(
+                    running[nm], node=live[int(rng.integers(len(live)))])
+                delta["upsert_running"].append(running[nm])
+            elif u < 0.27:                   # flip count_into_used
+                running[nm] = dict(
+                    running[nm],
+                    count_into_used=not running[nm]["count_into_used"])
+                delta["upsert_running"].append(running[nm])
+        for j in range(int(rng.integers(0, 12))):   # bind
+            nm = f"r{int(rng.integers(0, 130)):03d}x{k}{j}"
+            running[nm] = _sum_order_member(
+                rng, nm, live[int(rng.integers(len(live)))])
+            delta["upsert_running"].append(running[nm])
+        n_before = len(ds._run_order)
+        stats = ds.apply(**delta)
+        assert stats.path == "delta", stats.reason
+        pads += max(0, n_before - len(running))
+        ref = DeviceSnapshot(EngineConfig(), ds.meta.buckets)
+        ref.full_load(nodes.values(), pods.values(), running.values())
+        assert ds._state.nodes_np.used.dtype == np.float32
+        assert np.array_equal(ds._state.nodes_np.used,
+                              ref._state.nodes_np.used), k
+        assert np.array_equal(np.asarray(ds.snap.nodes.used),
+                              np.asarray(ref.snap.nodes.used)), k
+        assert np.array_equal(ds._run_counted, ref._run_counted), k
+    assert pads > 0 and ds.rebuilds == 0
+
+
+def test_used_counters_count_the_folded_members():
+    """ApplyStats.used_nodes counts the nodes whose `used` row was
+    re-summed, and used_rows the counted running rows folded into
+    them: every counted member of a dirtied node, not just the churn."""
+    nodes = [dict(name=f"n{i}", allocatable={"cpu": 1e4}) for i in range(4)]
+    running = [dict(name=f"r{j:02d}", node=f"n{j % 4}",
+                    requests={"cpu": 10.0}, count_into_used=j % 5 != 0)
+               for j in range(20)]
+    ds = DeviceSnapshot(EngineConfig())
+    ds.full_load(nodes, [dict(name="p0", requests={"cpu": 1.0})], running)
+    # r00 (n0) completes, r01 moves n1 -> n2, r40 binds to n0 uncounted.
+    stats = ds.apply(
+        remove_running=["r00"],
+        upsert_running=[dict(running[1], node="n2"),
+                        dict(name="r40", node="n0", requests={"cpu": 1.0},
+                             count_into_used=False)])
+    assert stats.path == "delta"
+    dirty = {"n0", "n1", "n2"}
+    assert stats.used_nodes == len(dirty)
+    assert stats.used_rows == sum(
+        r["node"] in dirty and r.get("count_into_used", True)
+        for r in ds._running.values())
+    assert stats.used_rows == 4 + 3 + 5   # n0, n1 less r01, n2 with r01
+    # A pod-only delta dirties no node: nothing is folded.
+    stats = ds.apply(upsert_pods=[dict(name="p1", requests={"cpu": 2.0})])
+    assert (stats.used_nodes, stats.used_rows) == (0, 0)

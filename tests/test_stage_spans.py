@@ -96,11 +96,18 @@ def test_delta_cycles_carry_the_stage_spans(thread_leak_check):
         # The apply's counts: a node bump, a bind and an arrival (4
         # records), each name examined by a handful of O(churn) passes.
         assert set(apply.attrs) == {"h2d_bytes", "path", "order_visited",
-                                    "churn_records"}
+                                    "churn_records", "used_rows",
+                                    "used_nodes"}
         assert apply.attrs["path"] == "delta"
         assert apply.attrs["churn_records"] >= 4
         assert (apply.attrs["churn_records"] <= apply.attrs["order_visited"]
                 <= 10 * apply.attrs["churn_records"])
+        # The `used` re-sum: n0 (bumped) and the new running pod's node
+        # n{(2 + k) % 4}, folded over every running pod on them.
+        dirty = {0, (2 + k) % 4}
+        assert apply.attrs["used_nodes"] == len(dirty)
+        assert apply.attrs["used_rows"] == sum(
+            j % 4 in dirty for j in range(3 + k))
         for name in APPLY:
             for s in by_name[name]:
                 assert s.parent_id == apply.span_id, name

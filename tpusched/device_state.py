@@ -47,7 +47,7 @@ import heapq
 import logging
 import time
 import traceback
-from bisect import bisect_left, insort
+from bisect import bisect_left
 from typing import Iterable, Mapping
 
 import jax
@@ -94,6 +94,10 @@ class ApplyStats:
     # churned name per pass plus one per bisect lookup (a rebuild counts
     # every record it re-sorts). O(churn) on the delta path.
     order_visited: int = 0
+    # The `used` re-sum: running rows folded into churned nodes' rows,
+    # and the nodes re-summed (0 on a rebuild, which sums every row).
+    used_rows: int = 0
+    used_nodes: int = 0
 
 
 @dataclasses.dataclass
@@ -231,9 +235,9 @@ class DeviceSnapshot:
         self._run_node_name: dict[str, str] = {}
         self._pod_group_name: dict[str, str] = {}
         self._run_pdb_key: dict[str, tuple] = {}
-        # node name -> its running pods' names, sorted (the `used`
-        # re-sum's member order).
-        self._run_by_node: dict[str, list[str]] = {}
+        # Host-only column in the running mirror's row order: the row's
+        # record has count_into_used (pad rows False). Never shipped.
+        self._run_counted = np.zeros(0, bool)
         self._state = None          # snapshot.BuiltState
         self._meta: SnapshotMeta | None = None
         self._device: ClusterSnapshot | None = None
@@ -378,9 +382,9 @@ class DeviceSnapshot:
 
     def _refresh_prev_maps(self) -> None:
         """Reverse maps the NEXT apply needs to find what a churned
-        record used to reference (old node, old group, old PDB), and
-        running pods by node, from every record (the rebuild's; an
-        apply carries them over with _update_prev_maps)."""
+        record used to reference (old node, old group, old PDB), from
+        every record (the rebuild's; an apply carries them over with
+        _update_prev_maps)."""
         self._run_node_name = {
             name: rec["node"] for name, rec in self._running.items()
         }
@@ -392,28 +396,13 @@ class DeviceSnapshot:
         for key, members in self._pdb_members.items():
             for name in members:
                 self._run_pdb_key[name] = key
-        self._run_by_node = {}
-        for name in self._run_order:
-            self._run_by_node.setdefault(self._running[name]["node"],
-                                         []).append(name)
 
     def _update_prev_maps(self, pods: set[str], running: set[str]) -> int:
-        """_refresh_prev_maps for the churned names only: a running pod
-        that left its node or moved leaves the old node's list, and is
-        inserted in name order into the new one's. Returns the names
-        examined."""
+        """_refresh_prev_maps for the churned names only. Returns the
+        names examined."""
         for name in running:
-            old = self._run_node_name.get(name)
             rec = self._running.get(name)
             new = rec["node"] if rec is not None else None
-            if old != new:
-                if old is not None:
-                    members = self._run_by_node[old]
-                    del members[_row_of(members, name)]
-                    if not members:
-                        del self._run_by_node[old]
-                if new is not None:
-                    insort(self._run_by_node.setdefault(new, []), name)
             if new is None:
                 self._run_node_name.pop(name, None)
             else:
@@ -480,6 +469,9 @@ class DeviceSnapshot:
         self._norm_nodes = dict(zip(self._node_order, b._nodes))
         self._norm_pods = dict(zip(self._pod_order, b._pods))
         self._norm_running = dict(zip(self._run_order, b._running))
+        self._run_counted = np.zeros(state.buckets.running_pods, bool)
+        self._run_counted[:len(b._running)] = [
+            bool(r["count_into_used"]) for r in b._running]
         # Compiled forms cache only what churn touches; the build just
         # burned every row, so start empty.
         self._pod_pc = {}
@@ -817,6 +809,8 @@ class DeviceSnapshot:
             rows = perm.astype(np.intp)
             for f, arr in list(vars(holder).items()):
                 setattr(holder, f, np.take(arr, rows, axis=0))
+        if run_perm is not None:
+            self._run_counted = self._run_counted[run_perm]
         if node_reorder:
             ni = st.run_np.node_idx
             st.run_np.node_idx = np.where(
@@ -831,30 +825,38 @@ class DeviceSnapshot:
         visited += len(pod_row) + len(run_row)
         laps.lap("apply.encode")
         for nm in node_churn:
-            i = st.node_index[nm]
-            _fill_node_row(st.nodes_np, i, self._norm_nodes[nm], intr, cfg)
-            # Re-sum counted members in name order: float-identical to a
-            # rebuild, never drifting through +=/-= pairs.
-            for member in self._run_by_node.get(nm, ()):
-                rrec = self._norm_running[member]
-                if rrec["count_into_used"]:
-                    for r, rn in enumerate(cfg.resources):
-                        st.nodes_np.used[i, r] += float(
-                            rrec["requests"].get(rn, 0.0)
-                        )
+            _fill_node_row(st.nodes_np, st.node_index[nm],
+                           self._norm_nodes[nm], intr, cfg)
         for nm in pod_churn:
             _fill_pod_row(st.pods_np, pod_row[nm], self._norm_pods[nm],
                           self._pod_pc[nm], intr, cfg, st.group_idx)
+        counted = self._run_counted
         for nm in run_churn:
-            _fill_running_row(st.run_np, run_row[nm],
-                              self._norm_running[nm], self._run_anti[nm],
-                              intr, cfg, st.node_index, st.pdb_idx)
+            rrec = self._norm_running[nm]
+            _fill_running_row(st.run_np, run_row[nm], rrec,
+                              self._run_anti[nm], intr, cfg, st.node_index,
+                              st.pdb_idx)
+            counted[run_row[nm]] = bool(rrec["count_into_used"])
         for i in node_pads:
             _pad_node_row(st.nodes_np, i)
         for i in pod_pads:
             _pad_pod_row(st.pods_np, i)
         for i in run_pads:
             _pad_running_row(st.run_np, i)
+            counted[i] = False
+        # Re-sum the churned nodes' `used` rows from the running rows as
+        # they will be scattered: each counted member's float32 requests
+        # added one at a time in ascending row (= name) order, the
+        # sequence build_state performs, so the row is bit-identical to
+        # a rebuild and never drifts through +=/-= pairs. np.add.at is
+        # unbuffered: duplicate node indices accumulate in index order.
+        # The extra mark slot absorbs pad rows' node_idx of -1.
+        mark = np.zeros(bk.nodes + 1, bool)
+        mark[[st.node_index[nm] for nm in node_churn]] = True
+        ni = st.run_np.node_idx
+        used_rows = np.flatnonzero(mark[ni] & counted)
+        np.add.at(st.nodes_np.used, ni[used_rows],
+                  st.run_np.requests[used_rows])
 
         # New atom/sig table rows + touched group/pdb scalars.
         for a in range(n_atoms0, len(intr.atoms)):
@@ -977,7 +979,8 @@ class DeviceSnapshot:
             path="delta", h2d_bytes=h2d, rows_scattered=rows_written,
             reordered=(node_perm is not None or pod_perm is not None
                        or run_perm is not None),
-            order_visited=visited,
+            order_visited=visited, used_rows=len(used_rows),
+            used_nodes=len(node_churn),
         )
 
     # -- warm-start residency (ROADMAP item 3) ------------------------------

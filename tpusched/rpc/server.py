@@ -1559,7 +1559,9 @@ class SchedulerService:
                             sp.attrs.update(
                                 h2d_bytes=stats.h2d_bytes, path=stats.path,
                                 order_visited=stats.order_visited,
-                                churn_records=stats.churn_records)
+                                churn_records=stats.churn_records,
+                                used_rows=stats.used_rows,
+                                used_nodes=stats.used_nodes)
                         apply_s = time.perf_counter() - t_a
                         snap, meta = session.device.snap, session.device.meta
                 except KeyError:
